@@ -154,8 +154,8 @@ def run(verbose=True):
                  timeit(jax.jit(flash_attention_ref), q, k, v)))
 
     qd = jnp.asarray(rng.standard_normal((4, 4, 64)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((16, 128, 2, 64)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((16, 128, 2, 64)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((16, 2, 128, 64)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((16, 2, 128, 64)), jnp.float32)
     bt = jnp.asarray(rng.integers(0, 16, (4, 4)), jnp.int32)
     ln = jnp.asarray([300, 400, 128, 512], jnp.int32)
     rows.append(("paged_attention_interp",

@@ -7,21 +7,27 @@ causality is enforced *inside the page walk* — key slot ``t`` of the
 gathered pages contributes to query row ``i`` only when
 ``t <= q_positions[lane, i]``.
 
-TPU adaptation notes:
-  * page gathering is done through the BlockSpec index map driven by a
-    *scalar-prefetched* block table (PrefetchScalarGridSpec) — the Pallas
-    analogue of vLLM's gather from the page pool, but resolved by the DMA
-    engine ahead of compute instead of per-warp pointer chasing;
-  * one (batch, kv_head) pair per grid step keeps the whole per-head state
-    (page tile + [Q, G] accumulator) in VMEM; pages stream over the
-    innermost grid dimension with the online-softmax accumulator in VMEM
-    scratch;
-  * page_size is a multiple of 128 so the K^T q matmul hits the MXU;
-  * int8 page pools ride the same specs: per-page-row scales are streamed
-    next to their pages and the dequant happens in-register, so HBM
-    traffic stays at the int8 footprint.
+Layouts (chosen for the TPU's (8, 128) tiling rule, which a block's last
+two dims must meet or span whole):
 
-Grid: (batch, kv_heads, pages_per_seq), pages innermost.
+  * page pools are ``[P, KV, page, hd]`` — the KV-head axis ahead of the
+    page axis, so one block takes every head of a page and each head's
+    ``[page, hd]`` tile is a whole trailing pair;
+  * int8 scales are ``[P, KV, page]`` and are applied to the score and
+    probability rows (``s * ks``, ``p * vs``), never to the pages, so no
+    in-kernel relayout is needed;
+  * q is regrouped to ``[B, KV, Q*G, hd]`` outside the kernel (row
+    ``r = qi*G + gi``), and the per-row causal bound rides as an int32
+    ``[B, Q*G, 1]`` column.
+
+Page gathering is done through the BlockSpec index map driven by a
+scalar-prefetched block table (PrefetchScalarGridSpec): the DMA engine
+resolves the gather ahead of compute.  Pages stream over the innermost
+grid dimension with a per-head online-softmax accumulator in VMEM
+scratch; each head is a 2-D ``[Q*G, hd] x [hd, page]`` product with f32
+accumulation.
+
+Grid: (batch, pages_per_seq), pages innermost.
 """
 from __future__ import annotations
 
@@ -35,11 +41,14 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -2.0e38
 
 
-def _mixed_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, scale: float, page: int,
-                  ks_ref=None, vs_ref=None):
-    pi = pl.program_id(2)
-    np_ = pl.num_programs(2)
+def _mixed_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
+                  scale: float, page: int, quant: bool):
+    if quant:
+        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        o_ref, acc_ref, m_ref, l_ref = rest
+    pi = pl.program_id(1)
+    kv_heads, rows = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(pi == 0)
     def _init():
@@ -47,99 +56,102 @@ def _mixed_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0].astype(jnp.float32)            # [Q, G, hd]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # [page, hd]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)         # [page, hd]
-    if ks_ref is not None:
-        k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-    if vs_ref is not None:
-        v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
+    pos_k = pi * page + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1)
+    mask = pos_k <= qpos_ref[0]                       # [rows, page]
+    for h in range(kv_heads):
+        q = q_ref[0, h]                               # [rows, hd]
+        k = k_ref[0, h]                               # [page, hd]
+        v = v_ref[0, h]
+        if quant:
+            k = k.astype(jnp.float32).astype(q.dtype)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * ks_ref[0, h:h + 1, :]             # per-key-row scale
+        s = jnp.where(mask, s, _NEG_INF)
 
-    s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    pos_k = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    qpos = qpos_ref[0]                                # [Q]
-    mask = pos_k <= qpos[:, None, None]               # causal page walk
-    s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_ref[h]                             # [rows, 1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[h] = m_cur
+        if quant:
+            p = p * vs_ref[0, h:h + 1, :]
+            v = v.astype(jnp.float32)
+        else:
+            p = p.astype(v.dtype)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    m_prev = m_ref[...]                               # [Q, G]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.where(mask, jnp.exp(s - m_cur[:, :, None]), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2)
-    m_ref[...] = m_cur
-    acc_ref[...] = acc_ref[...] * alpha[:, :, None] + jax.lax.dot_general(
-        p, v, (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(pi == np_ - 1)
+    @pl.when(pi == pl.num_programs(1) - 1)
     def _finalize():
-        o_ref[0, :, 0] = (acc_ref[...] /
-                          (l_ref[...][..., None] + 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / (l_ref[...] + 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_mixed(q, k_pages, v_pages, block_tables, q_positions, *,
                           scale=None, interpret: bool = False,
                           k_scales=None, v_scales=None):
-    """q: [B,Q,H,hd]; pages: [P,page,KV,hd]; tables: [B,PPS];
+    """q: [B,Q,H,hd]; pages: [P,KV,page,hd]; tables: [B,PPS];
     q_positions: [B,Q] (per-row sequence position, causal bound);
-    k_scales/v_scales: [P,page,KV] when the pages are int8."""
+    k_scales/v_scales: [P,KV,page] when the pages are int8."""
     b, qn, h, hd = q.shape
-    page = k_pages.shape[1]
-    kv = k_pages.shape[2]
+    kv, page = k_pages.shape[1], k_pages.shape[2]
     g = h // kv
+    rows = qn * g
     pps = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5
-    qr = q.reshape(b, qn, kv, g, hd)
+    quant = k_scales is not None
+    qr = q.reshape(b, qn, kv, g, hd).transpose(0, 2, 1, 3, 4)
+    qr = qr.reshape(b, kv, rows, hd)
+    qpos = jnp.repeat(q_positions.astype(jnp.int32), g, axis=1)[..., None]
 
-    grid = (b, kv, pps)
-    kernel = functools.partial(_mixed_kernel, scale=scale, page=page)
+    def at_lane(bi, pi, tables):
+        return (bi, 0, 0, 0)
 
-    def at_lane(bi, ki, pi, tables):
-        return (bi, 0)
+    def at_page(bi, pi, tables):
+        return (tables[bi, pi], 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, qn), at_lane),                       # q_positions
-        pl.BlockSpec((1, qn, 1, g, hd),
-                     lambda bi, ki, pi, tables: (bi, 0, ki, 0, 0)),
-        pl.BlockSpec((1, page, 1, hd),
-                     lambda bi, ki, pi, tables: (tables[bi, pi], 0, ki, 0)),
-        pl.BlockSpec((1, page, 1, hd),
-                     lambda bi, ki, pi, tables: (tables[bi, pi], 0, ki, 0)),
+        pl.BlockSpec((1, rows, 1), lambda bi, pi, tables: (bi, 0, 0)),
+        pl.BlockSpec((1, kv, rows, hd), at_lane),
+        pl.BlockSpec((1, kv, page, hd), at_page),
+        pl.BlockSpec((1, kv, page, hd), at_page),
     ]
-    inputs = [block_tables, q_positions, qr, k_pages, v_pages]
-    if k_scales is not None:
+    inputs = [block_tables, qpos, qr, k_pages, v_pages]
+    if quant:
         # scales stream next to their pages through the same gather
-        spec = pl.BlockSpec((1, page, 1),
-                            lambda bi, ki, pi, tables: (tables[bi, pi], 0, ki))
+        spec = pl.BlockSpec((1, kv, page),
+                            lambda bi, pi, tables: (tables[bi, pi], 0, 0))
         in_specs += [spec, spec]
-        inputs += [k_scales, v_scales]
-
-        def kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, ks, vs, o_ref,
-                   acc_ref, m_ref, l_ref):
-            _mixed_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
-                          acc_ref, m_ref, l_ref, scale=scale, page=page,
-                          ks_ref=ks, vs_ref=vs)
+        inputs += [k_scales.astype(jnp.float32),
+                   v_scales.astype(jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(b, pps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, qn, 1, g, hd),
-                               lambda bi, ki, pi, tables: (bi, 0, ki, 0, 0)),
+        out_specs=pl.BlockSpec((1, kv, rows, hd), at_lane),
         scratch_shapes=[
-            pltpu.VMEM((qn, g, hd), jnp.float32),
-            pltpu.VMEM((qn, g), jnp.float32),
-            pltpu.VMEM((qn, g), jnp.float32),
+            pltpu.VMEM((kv, rows, hd), jnp.float32),
+            pltpu.VMEM((kv, rows, 1), jnp.float32),
+            pltpu.VMEM((kv, rows, 1), jnp.float32),
         ],
     )
-
+    kernel = functools.partial(_mixed_kernel, scale=scale, page=page,
+                               quant=quant)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, qn, kv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, rows, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention_mixed",
     )(*inputs)
+    out = out.reshape(b, kv, qn, g, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, qn, h, hd)
 
 

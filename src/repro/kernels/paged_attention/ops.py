@@ -25,7 +25,7 @@ position must map to a key slot whose page holds real data (pad rows are
 given position 0, which reads the lane's first slot — written for any
 live lane — and their output is discarded by the caller).  When the page
 pools are int8, ``k_scales``/``v_scales`` carry the per-page-row
-dequantization scales ``[P, page, KV]``.
+dequantization scales ``[P, KV, page]``.
 """
 from __future__ import annotations
 
@@ -51,7 +51,7 @@ def _on_tpu() -> bool:
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     scale=None, impl: str = "auto", interpret: bool = False,
                     k_scales=None, v_scales=None):
-    """q: [B,H,hd]; pages: [P,page,KV,hd]; tables: [B,PPS]; lengths: [B]."""
+    """q: [B,H,hd]; pages: [P,KV,page,hd]; tables: [B,PPS]; lengths: [B]."""
     if impl not in ("auto", "kernel", "ref"):
         raise ValueError(f"unknown paged_attention impl {impl!r}")
     if impl == "ref" or (impl == "auto" and not _on_tpu()):

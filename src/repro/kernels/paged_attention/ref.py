@@ -7,9 +7,11 @@ causality is enforced inside the page walk by masking every key slot past
 the row's position.  The classic single-token decode oracle
 (:func:`paged_attention_ref`) is the ``q_len=1`` special case.
 
-Pages may optionally be int8-quantized with per-page-row scales
-(``[P, page, KV]``): gathered pages are dequantized before the score
-matmul, so only the pages a lane actually touches pay the dequant.
+Pools are ``[P, KV, page, hd]`` (the KV-head axis ahead of the page
+axis, the layout the TPU kernel tiles).  Pages may optionally be
+int8-quantized with per-page-row scales (``[P, KV, page]``): gathered
+pages are dequantized before the score matmul, so only the pages a lane
+actually touches pay the dequant.
 """
 from __future__ import annotations
 
@@ -21,13 +23,11 @@ _NEG_INF = -2.0e38
 def _gather_pages(pages, block_tables, scales, out_dtype):
     """pages[block_tables] -> [B, PPS*page, KV, hd], dequantized."""
     b, pps = block_tables.shape
-    page = pages.shape[1]
-    kv, hd = pages.shape[2], pages.shape[3]
-    g = pages[block_tables]                     # [B, PPS, page, KV, hd]
-    g = g.reshape(b, pps * page, kv, hd).astype(jnp.float32)
+    kv, page, hd = pages.shape[1], pages.shape[2], pages.shape[3]
+    g = pages[block_tables].astype(jnp.float32)  # [B, PPS, KV, page, hd]
     if scales is not None:
-        s = scales[block_tables].reshape(b, pps * page, kv)
-        g = g * s.astype(jnp.float32)[..., None]
+        g = g * scales[block_tables].astype(jnp.float32)[..., None]
+    g = g.transpose(0, 1, 3, 2, 4).reshape(b, pps * page, kv, hd)
     return g.astype(out_dtype)
 
 
@@ -37,18 +37,17 @@ def paged_attention_mixed_ref(q, k_pages, v_pages, block_tables, q_positions,
 
     q            [B, Q, H, hd]      (Q query rows per lane; pad rows are
                                      harmless — give them position 0)
-    k_pages      [P, page, KV, hd]  (global page pool; int8 if *_scales)
-    v_pages      [P, page, KV, hd]
+    k_pages      [P, KV, page, hd]  (global page pool; int8 if *_scales)
+    v_pages      [P, KV, page, hd]
     block_tables [B, PPS] int32     (page ids per sequence)
     q_positions  [B, Q] int32       (sequence position of each query row;
                                      row i attends key slots t <= pos[i])
-    k_scales     [P, page, KV] f32  (optional int8 per-page-row scales)
-    v_scales     [P, page, KV] f32
+    k_scales     [P, KV, page] f32  (optional int8 per-page-row scales)
+    v_scales     [P, KV, page] f32
     Returns      [B, Q, H, hd]
     """
     b, qn, h, hd = q.shape
-    page = k_pages.shape[1]
-    kv = k_pages.shape[2]
+    kv = k_pages.shape[1]
     g = h // kv
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5

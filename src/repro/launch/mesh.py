@@ -6,15 +6,11 @@ touches jax device state (smoke tests must keep seeing 1 CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:                                   # jax >= 0.4.38
-    from jax.sharding import AxisType
 
-    def _mesh_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:                    # older jax: Auto is the only mode
-    def _mesh_kwargs(n_axes: int) -> dict:
-        return {}
+def _mesh_kwargs(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,9 +24,10 @@ least-loaded dispatch (the A/B baseline).  A per-tenant
 ``draft_hints`` for repeated prompts (``--no-response-cache`` to
 disable; only drafts anything when ``--spec-k`` > 0).
 
-Runs one continuous-batching engine per tenant-replica on the reduced
-config, all sharing a FabricState (the PS fabric model injects PCIe-class
-interference when --interfere is set), with the multi-tenancy controller
+Runs one continuous-batching engine per tenant-replica (on the reduced
+config unless ``reduced=False`` / ``--published-widths``), all sharing a
+FabricState (the PS fabric model injects PCIe-class interference when
+--interfere is set), with the multi-tenancy controller
 steering quotas, placements and slice profiles per tenant.  Placement
 state lives in a shared DeviceLedger built from the TenantRegistry, the
 same bookkeeping the simulator uses — and ``--admit K`` exercises the
@@ -35,10 +36,30 @@ the live ledger mid-run; admitted ones get engines and traffic, the rest
 queue or are rejected.  Virtual time: replicas run in parallel — each
 engine owns an availability clock and the global clock advances to the
 next event (arrival, sample, step finish, admission).
+
+Each tenant's weights are built once and shared by its replicas; replica
+``j`` of every tenant lives on ``jax.devices()[j % n]``, so one process
+drives every chip of a host and a one-chip host keeps every replica on
+its only device.
 """
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
+
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_checkout_compile_cache() -> None:
+    """Entry points call this: JAX's persistent compile cache goes where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else to ``<checkout>/.jax_cache``
+    (a fixed path, so later runs of this checkout hit it)."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      str(CHECKOUT / ".jax_cache"))
 
 
 def warm_engine(eng, name: str, prompt_len: int) -> None:
@@ -91,8 +112,15 @@ def serve(arch: str = "stablelm_3b", requests: int = 32, qps: float = 4.0,
           migrate: bool = False, drains=None,
           gray_threshold: float = 2.5, gray_cooldown_s: float = 2.0,
           det_timing: bool = False, exact_tokens: bool = False,
-          unique_prompts: bool = False):
-    """Virtual-time multi-tenant serving run; returns per-tenant stats.
+          unique_prompts: bool = False, reduced: bool = True,
+          seq_cap: int = 128):
+    """Virtual-time multi-tenant serving run; returns per-tenant stats
+    (plus ``out["engines"]``, the engines by tenant, for inspection).
+
+    ``reduced=True`` (the default, what the CPU tests run) cuts the model
+    to ``configs.base.reduced``; ``reduced=False`` serves it at its
+    published widths and depth.  ``seq_cap`` is each engine's longest
+    sequence (prompt plus new tokens).
 
     ``listen=True`` (the ``--listen`` flag) turns on the gateway's
     backpressure policy: bounded per-tenant door queues of
@@ -161,10 +189,12 @@ def serve(arch: str = "stablelm_3b", requests: int = 32, qps: float = 4.0,
     """
     from collections import deque
 
+    import jax
     import numpy as np
-    from repro.configs.base import get_config, reduced
+    from repro.configs.base import get_config, reduced as reduced_cfg
     from repro.serving.directory import (CacheAwareRouter, PrefixDirectory,
                                          ResponseCache, RouterConfig)
+    from repro.models.model import Model
     from repro.serving.engine import ServingEngine
     from repro.serving.gateway import DoorConfig, Gateway
     from repro.serving.request import Request
@@ -186,7 +216,9 @@ def serve(arch: str = "stablelm_3b", requests: int = 32, qps: float = 4.0,
         raise SystemExit("--tenants and --replicas must be >= 1")
     if route not in ("cache", "load"):
         raise SystemExit("--route must be 'cache' or 'load'")
-    cfg = reduced(get_config(arch))
+    cfg = get_config(arch)
+    if reduced:
+        cfg = reduced_cfg(cfg)
     if exact_tokens:
         # float32 + reference attention: greedy argmax becomes a pure
         # function of the prompt, independent of batch shape and chunk
@@ -211,7 +243,7 @@ def serve(arch: str = "stablelm_3b", requests: int = 32, qps: float = 4.0,
             slow_replicas=1 if (migrate and replicas > 1) else 0)
     # spec_k is passed unconditionally: requesting speculation on the
     # dense backend must hit the engine's ValueError, not silently no-op
-    eng_kw = dict(max_slots=slots, seq_cap=128, backend=backend,
+    eng_kw = dict(max_slots=slots, seq_cap=seq_cap, backend=backend,
                   spec_k=spec_k)
     if exact_tokens:
         eng_kw["attn_impl"] = "ref"
@@ -227,12 +259,21 @@ def serve(arch: str = "stablelm_3b", requests: int = 32, qps: float = 4.0,
             kw["response_cache"] = rcaches.setdefault(name, ResponseCache())
         return kw
 
-    # one seed per TENANT, identical across its replicas: replicas of a
-    # model serve the same weights, so a redriven (or page-shipped)
-    # request regenerates the same greedy tokens on any of them
-    engines = {name: [ServingEngine(cfg, seed=seed + 17 * i,
-                                    **tenant_kw(name))
-                      for j in range(replicas)]
+    # one set of weights per TENANT, shared by its replicas: replicas of
+    # a model serve the same weights, so a redriven (or page-shipped)
+    # request regenerates the same greedy tokens on any of them.  Replica
+    # j sits on device j % n; placing arrays already on that device
+    # shares their buffers, so one chip holds one copy per tenant
+    devices = jax.devices()
+
+    def new_engines(tenant_seed, name, n):
+        params = Model(cfg).init(jax.random.key(tenant_seed))
+        return [ServingEngine(cfg, params, seed=tenant_seed,
+                              device=devices[j % len(devices)],
+                              **tenant_kw(name))
+                for j in range(n)]
+
+    engines = {name: new_engines(seed + 17 * i, name, replicas)
                for i, name in enumerate(names)}
     # cluster-wide KV reuse: every paged replica publishes its prefix
     # cache into a per-tenant content-hash directory, and dispatch
@@ -448,8 +489,7 @@ def serve(arch: str = "stablelm_3b", requests: int = 32, qps: float = 4.0,
     def on_admitted(spec, slots_, t):
         name = spec.name
         names.append(name)
-        engines[name] = [ServingEngine(cfg, seed=seed + 1000 + len(names),
-                                       **tenant_kw(name))]
+        engines[name] = new_engines(seed + 1000 + len(names), name, 1)
         routers[name] = wire_tenant(name)
         actuator.engines[name] = engines[name]
         actuator.compute_scales.setdefault(name, 1.0)
@@ -953,6 +993,7 @@ def serve(arch: str = "stablelm_3b", requests: int = 32, qps: float = 4.0,
                   f"({warm_n} warm lane(s), {cold_n} recompute, "
                   f"{sum(m['bytes'] for m in migrations) / 1e6:.2f} MB "
                   f"shipped)")
+    out["engines"] = engines
     out["gateway"] = gateway.counters()
     out["prometheus"] = gateway.prometheus(now[0])
     gateway.check()     # offered == completed+rejected+shed+expired+in_flight
@@ -972,6 +1013,11 @@ def serve(arch: str = "stablelm_3b", requests: int = 32, qps: float = 4.0,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm_3b")
+    ap.add_argument("--published-widths", action="store_true",
+                    help="serve the model at its published widths and depth "
+                         "(default: the reduced smoke-test cut)")
+    ap.add_argument("--seq-cap", type=int, default=128,
+                    help="longest sequence (prompt + new tokens) per engine")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--qps", type=float, default=4.0)
     ap.add_argument("--prompt-len", type=int, default=48)
@@ -1060,6 +1106,7 @@ def main():
                          "resurrected from the prefix directory")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_checkout_compile_cache()
     drains = []
     for spec in args.drain_at:
         try:
@@ -1085,7 +1132,8 @@ def main():
           recover=not args.no_recover,
           migrate=args.migrate, drains=drains or None,
           det_timing=args.det_timing,
-          unique_prompts=args.unique_prompts)
+          unique_prompts=args.unique_prompts,
+          reduced=not args.published_widths, seq_cap=args.seq_cap)
 
 
 if __name__ == "__main__":

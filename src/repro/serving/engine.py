@@ -18,6 +18,9 @@ Backends (``backend=`` ctor arg, same public API either way):
   prompts prefill in chunks interleaved with decode, and pool exhaustion
   triggers SLO-aware preemption instead of submit-time rejection.
 
+``device`` places the engine: its weights and KV state are committed to
+that device, so its jitted steps run there (None = the default device).
+
 Guardrail hook (paper §2.2, MPS-quota analogue): ``set_quota(frac)`` caps
 the engine's concurrency — the number of active decode slots and the
 prefill admission rate scale with the quota, bounding MXU occupancy the
@@ -98,7 +101,7 @@ class ServingEngine:
                  step_tokens: Optional[int] = None, attn_impl: str = "auto",
                  kv_dtype: str = "auto", prefix_cache: bool = True,
                  spec_k: int = 0, spec_ngram: int = 3,
-                 response_cache=None):
+                 response_cache=None, device=None):
         if backend not in ("dense", "paged"):
             raise ValueError(f"unknown backend {backend!r}")
         if kv_dtype != "auto" and backend == "dense":
@@ -132,7 +135,10 @@ class ServingEngine:
         self.policy = policy
         if params is None:
             params = self.model.init(jax.random.key(seed))
+        if device is not None:
+            params = jax.device_put(params, device)
         self.params = params
+        self.device = device
         self.max_slots = max_slots
         self.seq_cap = seq_cap
         self.backend = backend
@@ -152,7 +158,7 @@ class ServingEngine:
                 policy=policy, attn_impl=attn_impl, kv_dtype=kv_dtype,
                 prefix_cache=prefix_cache, spec_k=spec_k,
                 spec_ngram=spec_ngram, response_cache=self.response_cache,
-                seed=seed)
+                seed=seed, device=device)
             self.kv = self.runtime.kv
             # the scheduler's waiting deque doubles as the engine queue
             # (same object for the lifetime of the engine, so load-based
@@ -169,6 +175,8 @@ class ServingEngine:
                                page_size=page_size)
         cplan = self.model.cache_plan(max_slots, seq_cap, policy)
         self.cache = init_cache_from_plan(cplan)
+        if device is not None:
+            self.cache = jax.device_put(self.cache, device)
         self._decode_fn = jax.jit(
             lambda p, c, t, q: decode_step(p, cfg, c, t, q, policy))
         self._prefill_fn = jax.jit(

@@ -101,7 +101,10 @@ _bucket_rows = bucket_rows
 
 class PagedRuntime:
     """One tenant-replica's paged serving state: page pools + scheduler +
-    the jitted fused mixed prefill+decode forward pass."""
+    the jitted fused mixed prefill+decode forward pass.  ``device``
+    commits the page pools and step inputs there (None = the default
+    device); the jitted step runs where its committed inputs live, so
+    pass weights already placed on the same device."""
 
     def __init__(self, cfg: ModelConfig, params, *, max_slots: int = 8,
                  seq_cap: int = 256, page_size: int = 16,
@@ -111,7 +114,7 @@ class PagedRuntime:
                  policy: ShardPolicy = NO_POLICY, attn_impl: str = "auto",
                  kv_dtype: str = "auto", prefix_cache: bool = True,
                  spec_k: int = 0, spec_ngram: int = 3,
-                 response_cache=None, seed: int = 0):
+                 response_cache=None, seed: int = 0, device=None):
         reason = paged_unsupported_reason(cfg)
         if reason is not None:
             raise ValueError(
@@ -123,6 +126,7 @@ class PagedRuntime:
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         self.cfg = cfg
+        self.device = device
         self.params = params
         self.policy = policy
         self.page = page_size
@@ -156,22 +160,26 @@ class PagedRuntime:
         # startup; compile time must not pollute the virtual clock's
         # measured per-step compute)
         self._mixed_exec: Dict[tuple, Any] = {}
+        self.compile_s: Dict[tuple, float] = {}     # seconds per bucket
         self._rng = np.random.default_rng(seed)
 
     # ------------------------------------------------------------- pools
     def _init_pools(self) -> Dict[str, Any]:
         a = self.cfg.attn
         dt = jnp.int8 if self.kv_quant else jnp.dtype(self.cfg.dtype)
-        shape = (self.pool_pages + 1, self.page, a.num_kv_heads, a.head_dim)
-        sshape = (self.pool_pages + 1, self.page, a.num_kv_heads)
+        # [P, KV, page, hd]: the head axis ahead of the page axis is the
+        # layout the TPU kernel tiles (see kernels/paged_attention)
+        shape = (self.pool_pages + 1, a.num_kv_heads, self.page, a.head_dim)
+        sshape = (self.pool_pages + 1, a.num_kv_heads, self.page)
 
         def pool(stack: int = 0):
             s = (stack,) + shape if stack else shape
-            d = {"k": jnp.zeros(s, dt), "v": jnp.zeros(s, dt)}
+            d = {"k": jnp.zeros(s, dt, device=self.device),
+                 "v": jnp.zeros(s, dt, device=self.device)}
             if self.kv_quant:
                 ss = (stack,) + sshape if stack else sshape
-                d["k_scale"] = jnp.zeros(ss, jnp.float32)
-                d["v_scale"] = jnp.zeros(ss, jnp.float32)
+                d["k_scale"] = jnp.zeros(ss, jnp.float32, device=self.device)
+                d["v_scale"] = jnp.zeros(ss, jnp.float32, device=self.device)
             return d
 
         pools: Dict[str, Any] = {}
@@ -190,18 +198,18 @@ class PagedRuntime:
         per-row and store the scales beside the pages."""
         if not self.kv_quant:
             return {**pool,
-                    "k": pool["k"].at[page_ids, offs].set(
+                    "k": pool["k"].at[page_ids, :, offs].set(
                         k.astype(pool["k"].dtype)),
-                    "v": pool["v"].at[page_ids, offs].set(
+                    "v": pool["v"].at[page_ids, :, offs].set(
                         v.astype(pool["v"].dtype))}
         kq, ks = attn_mod._quantize_kv(k)
         vq, vs = attn_mod._quantize_kv(v)
         return {**pool,
-                "k": pool["k"].at[page_ids, offs].set(kq),
-                "v": pool["v"].at[page_ids, offs].set(vq),
-                "k_scale": pool["k_scale"].at[page_ids, offs].set(
+                "k": pool["k"].at[page_ids, :, offs].set(kq),
+                "v": pool["v"].at[page_ids, :, offs].set(vq),
+                "k_scale": pool["k_scale"].at[page_ids, :, offs].set(
                     ks.astype(jnp.float32)),
-                "v_scale": pool["v_scale"].at[page_ids, offs].set(
+                "v_scale": pool["v_scale"].at[page_ids, :, offs].set(
                     vs.astype(jnp.float32))}
 
     def _walk_layers(self, params, pools, h, layer_fn):
@@ -351,10 +359,12 @@ class PagedRuntime:
         key = (tokens.shape[0], bts.shape[1], last_rows.shape[0])
         fn = self._mixed_exec.get(key)
         if fn is None:
+            t0 = time.perf_counter()
             fn = self._mixed_fn.lower(
                 self.params, self.pools, tokens, positions, n_rows, bts,
                 last_rows).compile()
             self._mixed_exec[key] = fn
+            self.compile_s[key] = time.perf_counter() - t0
         t0 = time.perf_counter()
         logits, self.pools = fn(self.params, self.pools, tokens, positions,
                                 n_rows, bts, last_rows)
@@ -426,8 +436,8 @@ class PagedRuntime:
             bts[r0:r0 + n] = self.kv.block_table(lane[1].req.req_id, width)
 
         logits, report.compute_s = self._run_mixed(
-            jnp.asarray(tokens), jnp.asarray(positions), np.int32(n_rows),
-            jnp.asarray(bts), jnp.asarray(last_rows))
+            *jax.device_put((tokens, positions, np.int32(n_rows), bts,
+                             last_rows), self.device))
         next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
 
         for lane in lanes:

@@ -13,14 +13,7 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
-
-try:
-    import zstandard
-except ImportError:                       # container images without zstd
-    zstandard = None
-import zlib
-
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
+import zstandard
 
 
 def _flatten(tree, prefix="") -> Dict[str, np.ndarray]:
@@ -44,10 +37,7 @@ def save(path: str, tree: Any, metadata: Dict[str, Any] | None = None) -> int:
         },
     }
     raw = msgpack.packb(payload, use_bin_type=True)
-    if zstandard is not None:
-        comp = zstandard.ZstdCompressor(level=3).compress(raw)
-    else:
-        comp = zlib.compress(raw, 6)
+    comp = zstandard.ZstdCompressor(level=3).compress(raw)
     tmp = path + ".tmp"
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     with open(tmp, "wb") as f:
@@ -61,13 +51,7 @@ def load(path: str, like: Any | None = None) -> Tuple[Any, Dict[str, Any]]:
     structure; otherwise returns the flat dict."""
     with open(path, "rb") as f:
         blob = f.read()
-    if blob[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise ImportError("checkpoint is zstd-compressed but the "
-                              "zstandard module is unavailable")
-        raw = zstandard.ZstdDecompressor().decompress(blob)
-    else:
-        raw = zlib.decompress(blob)
+    raw = zstandard.ZstdDecompressor().decompress(blob)
     payload = msgpack.unpackb(raw, raw=False)
     arrays = {
         k: np.frombuffer(v["data"],

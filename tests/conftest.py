@@ -6,18 +6,6 @@ import pytest
 
 jax.config.update("jax_platform_name", "cpu")
 
-# The pinned container image does not ship `hypothesis`; fall back to the
-# deterministic sampling stub so property tests still run (see
-# tests/_hypothesis_stub.py).
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(__file__))
-    from _hypothesis_stub import install as _install_hypothesis_stub
-    _install_hypothesis_stub()
-
 
 def pytest_configure(config):
     config.addinivalue_line(

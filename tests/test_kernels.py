@@ -67,8 +67,8 @@ def test_flash_attention_block_shape_invariance(bq, bk, s):
 ])
 def test_paged_attention_allclose(b, h, kv, hd, page, pps, npages):
     q = jnp.asarray(RNG.standard_normal((b, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
+    vp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
     bt = jnp.asarray(RNG.integers(0, npages, (b, pps)), jnp.int32)
     lens = jnp.asarray(RNG.integers(1, pps * page, (b,)), jnp.int32)
     # impl="kernel" pins the Pallas kernel (interpret mode on CPU); the
@@ -85,8 +85,8 @@ def test_paged_attention_ignores_pages_beyond_length(impl):
     """Property: garbage in pages past `lengths` must not leak into output."""
     b, h, kv, hd, page, pps, npages = 1, 2, 2, 64, 128, 4, 8
     q = jnp.asarray(RNG.standard_normal((b, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
+    vp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
     bt = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
     lens = jnp.asarray([130], jnp.int32)
     out1 = paged_attention(q, kp, vp, bt, lens, impl=impl)
@@ -105,8 +105,8 @@ def test_paged_attention_mixed_allclose(b, qn, h, kv, hd, page, pps, npages):
     """Ragged mixed rows (per-row causal positions, including pad rows at
     position 0): Pallas kernel (interpret) vs oracle."""
     q = jnp.asarray(RNG.standard_normal((b, qn, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
+    vp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
     bt = jnp.asarray(RNG.integers(0, npages, (b, pps)), jnp.int32)
     # lane 0: a prefill-style run of consecutive positions; other lanes:
     # random valid positions with trailing pad rows at 0
@@ -124,8 +124,8 @@ def test_paged_attention_mixed_q1_matches_decode():
     """Property: the ragged path with q_len=1 IS the decode path."""
     b, h, kv, hd, page, pps, npages = 2, 4, 2, 64, 128, 4, 16
     q = jnp.asarray(RNG.standard_normal((b, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
+    vp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
     bt = jnp.asarray(RNG.integers(0, npages, (b, pps)), jnp.int32)
     lens = jnp.asarray([200, 400], jnp.int32)
     dec = paged_attention(q, kp, vp, bt, lens, impl="ref")
@@ -142,13 +142,13 @@ def test_paged_attention_mixed_causal_within_chunk(impl):
     leaves rows <= p bit-identical)."""
     b, qn, h, kv, hd, page, pps, npages = 1, 4, 2, 2, 64, 128, 2, 4
     q = jnp.asarray(RNG.standard_normal((b, qn, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
+    vp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
     bt = jnp.asarray([[0, 1]], jnp.int32)
     qpos = jnp.asarray([[60, 61, 62, 63]], jnp.int32)
     out1 = paged_attention_mixed(q, kp, vp, bt, qpos, impl=impl)
-    kp2 = kp.at[0, 64:].set(1e4).at[1].set(1e4)     # poison past pos 63
-    vp2 = vp.at[0, 64:].set(-1e4).at[1].set(-1e4)
+    kp2 = kp.at[0, :, 64:].set(1e4).at[1].set(1e4)  # poison past pos 63
+    vp2 = vp.at[0, :, 64:].set(-1e4).at[1].set(-1e4)
     out2 = paged_attention_mixed(q, kp2, vp2, bt, qpos, impl=impl)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-5)
 
@@ -158,8 +158,8 @@ def test_paged_attention_int8_pages_close(impl):
     """int8 pages + per-page-row scales stay close to the fp path."""
     b, qn, h, kv, hd, page, pps, npages = 2, 4, 4, 2, 64, 128, 2, 8
     q = jnp.asarray(RNG.standard_normal((b, qn, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
+    vp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
     bt = jnp.asarray(RNG.integers(0, npages, (b, pps)), jnp.int32)
     qpos = jnp.asarray(RNG.integers(0, pps * page, (b, qn)), jnp.int32)
 
@@ -182,8 +182,8 @@ def test_paged_attention_bucketed_width_invariance():
     runtime's width bucketing) must not change the output."""
     b, h, kv, hd, page, npages = 2, 4, 2, 64, 128, 8
     q = jnp.asarray(RNG.standard_normal((b, h, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((npages, page, kv, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
+    vp = jnp.asarray(RNG.standard_normal((npages, kv, page, hd)), jnp.float32)
     bt = jnp.asarray(RNG.integers(0, npages, (b, 4)), jnp.int32)
     lens = jnp.asarray([100, 200], jnp.int32)    # <= 2 pages live
     wide = paged_attention(q, kp, vp, bt, lens, impl="ref")
