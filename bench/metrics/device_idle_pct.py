@@ -1,0 +1,9 @@
+"""device_idle_pct: share of the traced window in which no operation ran
+on the device (1 - union of the operations' intervals / window)."""
+
+
+def read(run):
+    tr = run.log.trace
+    if tr is None or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
