@@ -144,10 +144,7 @@ class ServingEngine:
         self.backend = backend
         self.quota = 1.0
         self.metrics = TenantMetrics()
-        # optional serving/trace.FlightRecorder: ``finalize_step`` folds
-        # each step into per-request timelines.  None (the default) is
-        # the zero-cost path — a single guard, no recorder calls.
-        self.tracer = None
+        self._tracer = None
         self._rng = np.random.default_rng(seed)
         if backend == "paged":
             from repro.serving.paged_runtime import PagedRuntime
@@ -183,6 +180,26 @@ class ServingEngine:
             lambda p, b: prefill(p, cfg, b, policy, seq_cap=seq_cap))
 
     # ------------------------------------------------------------------ API
+    @property
+    def tracer(self):
+        """Optional serving/trace.FlightRecorder: ``finalize_step`` folds
+        each step into per-request timelines, the paged runtime records
+        its step spans and counters on it, and every backend compile in
+        the process counts under ``counters["compiles"]`` while it is
+        attached.  None (the default) is the zero-cost path — a single
+        guard, no recorder calls."""
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        if self._tracer is not None:
+            self._tracer.unwatch_compiles()
+        self._tracer = tracer
+        if self.runtime is not None:
+            self.runtime.tracer = tracer
+        if tracer is not None:
+            tracer.watch_compiles()
+
     def set_quota(self, frac: float) -> None:
         self.quota = float(np.clip(frac, 0.1, 1.0))
         if self.runtime is not None:

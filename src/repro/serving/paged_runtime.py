@@ -56,7 +56,6 @@ study (OLMo-2, StableLM); everything else keeps the dense backend.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -64,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import LayerSpec, ModelConfig
+from repro.core.obs import Stopwatch
 from repro.kernels.paged_attention.ops import paged_attention_mixed
 from repro.models import attention as attn_mod
 from repro.models.common import NO_POLICY, ShardPolicy, apply_rope, rms_norm, shard
@@ -162,6 +162,10 @@ class PagedRuntime:
         self._mixed_exec: Dict[tuple, Any] = {}
         self.compile_s: Dict[tuple, float] = {}     # seconds per bucket
         self._rng = np.random.default_rng(seed)
+        # the engine's tracer (ServingEngine.tracer sets it); with a clock
+        # it gets the step.* spans, else the stopwatch times the step
+        self.tracer = None
+        self._watch = Stopwatch()
 
     # ------------------------------------------------------------- pools
     def _init_pools(self) -> Dict[str, Any]:
@@ -216,8 +220,10 @@ class PagedRuntime:
         """Run ``layer_fn(lp, h, layer, pool) -> (h, pool)`` over the
         prefix layers and the scanned period stack, threading each layer's
         page-pool dict through (the stacked period pools are
-        indexed/updated per scan step, mirroring the dense decode path),
-        then apply the final norm."""
+        indexed/updated per scan step, mirroring the dense decode path).
+        Named scopes: ``kv_pool`` on the per-layer pool slice and its
+        write-back, ``layer_weights`` on the scan, whose body slices each
+        layer's weights out of the stack."""
         cfg = self.cfg
         new_pools = dict(pools)
         if cfg.prefix:
@@ -233,21 +239,24 @@ class PagedRuntime:
                 lp_stack, idx = xs
                 for i, layer in enumerate(cfg.period):
                     sub = f"sub{i}"
-                    pool_i = {key: jax.lax.dynamic_index_in_dim(
-                        pp[sub][key], idx, 0, keepdims=False)
-                        for key in pp[sub]}
+                    with jax.named_scope("kv_pool"):
+                        pool_i = {key: jax.lax.dynamic_index_in_dim(
+                            pp[sub][key], idx, 0, keepdims=False)
+                            for key in pp[sub]}
                     hh, pool_i = layer_fn(lp_stack[sub], hh, layer, pool_i)
-                    pp = {**pp, sub: {
-                        key: jax.lax.dynamic_update_index_in_dim(
-                            pp[sub][key], pool_i[key], idx, 0)
-                        for key in pp[sub]}}
+                    with jax.named_scope("kv_pool"):
+                        pp = {**pp, sub: {
+                            key: jax.lax.dynamic_update_index_in_dim(
+                                pp[sub][key], pool_i[key], idx, 0)
+                            for key in pp[sub]}}
                 return (hh, pp), ()
 
             idxs = jnp.arange(cfg.repeats, dtype=jnp.int32)
-            (h, period_pools), _ = jax.lax.scan(
-                body, (h, pools["period"]), (params["period"], idxs))
+            with jax.named_scope("layer_weights"):
+                (h, period_pools), _ = jax.lax.scan(
+                    body, (h, pools["period"]), (params["period"], idxs))
             new_pools["period"] = period_pools
-        return rms_norm(h, params["final_norm"], cfg.norm_eps), new_pools
+        return h, new_pools
 
     # ------------------------------------------------ forward: fused mixed
     def _mixed_layer(self, lp, h, layer: LayerSpec, positions, qpos,
@@ -257,16 +266,20 @@ class PagedRuntime:
         per-row positions inside the page walk.  Mirrors
         ``attn_mod.gqa_prefill`` numerics (same einsums, same f32 masked
         softmax) with the gathered pages standing in for the in-context
-        K/V."""
+        K/V.  Each part runs under the named scope a device trace
+        attributes its operations by (``attn_in``, ``kv_scatter``,
+        ``attn_kernel``, ``attn_out``, ``ffn``)."""
         cfg, policy = self.cfg, self.policy
         ap = lp["attn"]
-        xin = rms_norm(h, lp["norm1"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", xin, ap["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", xin, ap["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", xin, ap["wv"])
-        q = shard(apply_rope(q, positions, cfg.rope_theta), policy.heads)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        pool = self._scatter(pool, k[0], v[0], page_ids, offs)
+        with jax.named_scope("attn_in"):
+            xin = rms_norm(h, lp["norm1"], cfg.norm_eps)
+            q = jnp.einsum("bsd,dhk->bshk", xin, ap["wq"])
+            k = jnp.einsum("bsd,dhk->bshk", xin, ap["wk"])
+            v = jnp.einsum("bsd,dhk->bshk", xin, ap["wv"])
+            q = shard(apply_rope(q, positions, cfg.rope_theta), policy.heads)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("kv_scatter"):
+            pool = self._scatter(pool, k[0], v[0], page_ids, offs)
         kwargs = {}
         if self.kv_quant:
             kwargs = dict(k_scales=pool["k_scale"],
@@ -278,14 +291,17 @@ class PagedRuntime:
         # lanes padded to Q) amortises the gather but measured ~3x slower
         # on the CPU oracle because padding dominates — on TPU the Q>1
         # kernel path is the one to switch to (see ROADMAP)
-        ctx = paged_attention_mixed(q[0][:, None].astype(h.dtype),
-                                    pool["k"], pool["v"], block_tables,
-                                    qpos[:, None], impl=self.attn_impl,
-                                    **kwargs)                 # [T, 1, H, hd]
-        out = jnp.einsum("bshk,hkd->bsd",
-                         ctx[None, :, 0].astype(h.dtype), ap["wo"])
-        h = h + shard(out, policy.act)
-        h, _, _ = _apply_ffn(lp, h, layer, cfg, policy)
+        with jax.named_scope("attn_kernel"):
+            ctx = paged_attention_mixed(q[0][:, None].astype(h.dtype),
+                                        pool["k"], pool["v"], block_tables,
+                                        qpos[:, None], impl=self.attn_impl,
+                                        **kwargs)             # [T, 1, H, hd]
+        with jax.named_scope("attn_out"):
+            out = jnp.einsum("bshk,hkd->bsd",
+                             ctx[None, :, 0].astype(h.dtype), ap["wo"])
+            h = h + shard(out, policy.act)
+        with jax.named_scope("ffn"):
+            h, _, _ = _apply_ffn(lp, h, layer, cfg, policy)
         return h, pool
 
     def _mixed_impl(self, params, pools, tokens, positions, n_rows,
@@ -307,14 +323,17 @@ class PagedRuntime:
         # never sees an empty row; their output is discarded
         qpos = jnp.where(valid, positions, 0)
         positions2 = qpos[None]
-        h = embed_tokens(params, cfg, tokens[None], policy)
+        with jax.named_scope("embed"):
+            h = embed_tokens(params, cfg, tokens[None], policy)
         h, new_pools = self._walk_layers(
             params, pools, h,
             lambda lp, hh, layer, pool: self._mixed_layer(
                 lp, hh, layer, positions2, qpos, page_ids, offs,
                 block_tables, pool))
-        h_last = h[0][last_rows][None]                   # [1, L, d]
-        logits = _logits(params, cfg, h_last, policy)[0]
+        with jax.named_scope("logits"):
+            h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+            h_last = h[0][last_rows][None]               # [1, L, d]
+            logits = _logits(params, cfg, h_last, policy)[0]
         return logits, new_pools
 
     # ------------------------------------------------------------ engine API
@@ -352,46 +371,82 @@ class PagedRuntime:
         return self.sched.drain_for_redrive()
 
     # ------------------------------------------------------------ fused step
+    def _span(self, name: str, **args):
+        """The block as span ``name`` on the attached tracer when it has a
+        clock, else under this runtime's stopwatch; either one's ``dur``
+        is the block's seconds once it exits."""
+        tr = self.tracer
+        if tr is None or tr.clock is None:
+            return self._watch
+        return tr.scope(name, **args)
+
     def _run_mixed(self, tokens, positions, n_rows, bts, last_rows):
         """Execute the fused forward for this (rows, width, logit-rows)
-        bucket, AOT-compiling the bucket on first sight so compile time
-        never enters the measured compute.  Returns (logits, compute_s)."""
+        bucket, AOT-compiling the bucket on first sight (``step.compile``)
+        so compile time never enters the measured compute
+        (``step.device``: the call and its ``block_until_ready``).
+        Returns (logits, compute_s)."""
         key = (tokens.shape[0], bts.shape[1], last_rows.shape[0])
         fn = self._mixed_exec.get(key)
         if fn is None:
-            t0 = time.perf_counter()
-            fn = self._mixed_fn.lower(
-                self.params, self.pools, tokens, positions, n_rows, bts,
-                last_rows).compile()
+            with self._span("step.compile", rows=key[0], width=key[1],
+                            logits=key[2]) as sp:
+                fn = self._mixed_fn.lower(
+                    self.params, self.pools, tokens, positions, n_rows, bts,
+                    last_rows).compile()
             self._mixed_exec[key] = fn
-            self.compile_s[key] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        logits, self.pools = fn(self.params, self.pools, tokens, positions,
-                                n_rows, bts, last_rows)
-        logits = jax.block_until_ready(logits)
-        return logits, time.perf_counter() - t0
+            self.compile_s[key] = sp.dur
+        with self._span("step.device") as sp:
+            logits, self.pools = fn(self.params, self.pools, tokens,
+                                    positions, n_rows, bts, last_rows)
+            logits = jax.block_until_ready(logits)
+        return logits, sp.dur
 
     def step(self) -> StepReport:
-        log_mark = len(self.sched.preempt_log)
-        plan = self.sched.plan()
-        report = StepReport(kind="idle")
-        report.preempted = [s.req for s in plan.preempted]
-        # every preemption happens inside plan(): the log's new tail is
-        # exactly this step's (victim, beneficiary) pairs — the flight
-        # recorder attaches the beneficiary to the victim's timeline
-        report.preempt_pairs = list(self.sched.preempt_log[log_mark:])
-        report.prefix_hit_tokens = plan.prefix_hit_tokens
+        """One fused step, as consecutive spans on an attached tracer:
+        ``step.plan``, ``step.pack``, ``step.put``, ``step.compile`` (a
+        new bucket only), ``step.device``, ``step.fetch`` (argmax and
+        host copy), ``step.commit``; and the counters ``steps``, ``rows``
+        (real token rows) and ``rows_padded`` (rows of the bucket)."""
+        with self._span("step.plan"):
+            log_mark = len(self.sched.preempt_log)
+            plan = self.sched.plan()
+            report = StepReport(kind="idle")
+            report.preempted = [s.req for s in plan.preempted]
+            # every preemption happens inside plan(): the log's new tail
+            # is exactly this step's (victim, beneficiary) pairs — the
+            # flight recorder attaches the beneficiary to the victim's
+            # timeline
+            report.preempt_pairs = list(self.sched.preempt_log[log_mark:])
+            report.prefix_hit_tokens = plan.prefix_hit_tokens
         if plan.empty:
             return report
         decodes, prefills = plan.decodes, plan.prefills
         report.kind = ("mixed" if decodes and prefills
                        else "decode" if decodes else "prefill")
+        with self._span("step.pack"):
+            n_rows, arrays, lanes = self._pack(decodes, prefills)
+        with self._span("step.put"):
+            args = jax.device_put(arrays, self.device)
+        logits, report.compute_s = self._run_mixed(*args)
+        with self._span("step.fetch"):
+            next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
+        with self._span("step.commit"):
+            self._commit(lanes, next_tokens, report)
+        tr = self.tracer
+        if tr is not None:
+            tr.count("steps")
+            tr.count("rows", n_rows)
+            tr.count("rows_padded", len(arrays[0]))
+        return report
 
-        # pack the step's real tokens back to back: 1+len(draft) rows per
-        # decode lane (the base feedback token plus its speculative
-        # verify rows), ``clen`` rows per prefill chunk — cost tracks
-        # live tokens, and the row/width/logit buckets keep the jit shape
-        # set bounded
+    def _pack(self, decodes, prefills):
+        """Pack the step's real tokens back to back: 1+len(draft) rows per
+        decode lane (the base feedback token plus its speculative verify
+        rows), ``clen`` rows per prefill chunk — cost tracks live tokens,
+        and the row/width/logit buckets keep the jit shape set bounded.
+        Returns (real rows, (tokens, positions, n_rows, block tables,
+        last rows), lanes)."""
         n_rows = sum(1 + len(s.draft) for s in decodes) \
             + sum(c for _, _, c in prefills)
         # every decode row needs its logits for verification; prefill
@@ -434,12 +489,12 @@ class PagedRuntime:
         bts = np.zeros((t, width), np.int32)
         for (r0, n), lane in zip(row_of, lanes):
             bts[r0:r0 + n] = self.kv.block_table(lane[1].req.req_id, width)
+        return n_rows, (tokens, positions, np.int32(n_rows), bts,
+                        last_rows), lanes
 
-        logits, report.compute_s = self._run_mixed(
-            *jax.device_put((tokens, positions, np.int32(n_rows), bts,
-                             last_rows), self.device))
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
-
+    def _commit(self, lanes, next_tokens, report: StepReport) -> None:
+        """Commit each lane's tokens from the step's argmax into the
+        scheduler, the requests and ``report``."""
         for lane in lanes:
             if lane[0] == "d":
                 _, s, li, q = lane
@@ -496,4 +551,3 @@ class PagedRuntime:
                     if s.req.generated >= s.req.max_new_tokens:
                         self.sched.complete(s)
                         report.completed.append(s.req)
-        return report

@@ -46,9 +46,13 @@ affordable.  Export: Chrome/Perfetto ``trace_event`` JSON
 (:meth:`FlightRecorder.table`: ``p99 = X ms door + Y ms sched + ...``).
 
 Tracing is opt-in and zero-cost when off: every producer call site is
-guarded by ``if tracer is not None`` and timestamps are the harness's
-own virtual-clock stamps — attaching a recorder never perturbs the
-clock, so traced and untraced runs are token- and timing-identical.
+guarded by ``if tracer is not None``.  Request stamps are always the
+harness's own: under a virtual clock attaching a recorder never perturbs
+the clock, so traced and untraced runs are token- and timing-identical.
+A recorder built with a ``clock`` (the wall-clock harness's run clock)
+also records the paged runtime's step spans (``step.plan`` ...
+``step.commit``, see ``serving/paged_runtime.py``) and its counters on
+that clock, so request segments and step spans share one timeline.
 """
 from __future__ import annotations
 
@@ -214,13 +218,15 @@ class FlightRecorder(Tracer):
     ``keep_slowest`` full timelines are retained per tenant per
     ``window_s`` bucket of terminal time (tail exemplars), plus every
     request whose lifetime overlapped a controller action; summaries
-    (bounded deques) are kept for all requests.  All stamps are the
-    harness's virtual-clock values — the recorder never reads a clock.
+    (bounded deques) are kept for all requests.  Request stamps are the
+    harness's own; the recorder reads ``clock`` only for the spans of
+    :meth:`Tracer.scope` (None: it never reads a clock).
     """
 
     def __init__(self, keep_slowest: int = 8, window_s: float = 10.0,
-                 max_summaries: int = 8192, max_action_exemplars: int = 512):
-        super().__init__()
+                 max_summaries: int = 8192, max_action_exemplars: int = 512,
+                 clock=None):
+        super().__init__(clock)
         self.keep_slowest = keep_slowest
         self.window_s = window_s
         self._live: Dict[Tuple[str, int], RequestTimeline] = {}

@@ -7,6 +7,7 @@ lowering gaps).  The topology is described inside a fixture, never while
 a module is imported, and every compile stays in this process.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,9 +93,25 @@ def test_fused_step_compiles_at_published_widths(one_chip, monkeypatch):
         _spec((rows,), "int32", one_chip), _spec((rows,), "int32", one_chip),
         _spec((), "int32", one_chip), _spec((rows, width), "int32", one_chip),
         _spec((logits,), "int32", one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     mem = compiled.memory_analysis()
     # the donated pools alias the returned pools: no second pool copy
     pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                      for x in jax.tree.leaves(rt.pools))
     assert mem.alias_size_in_bytes >= pool_bytes
+    # every op that moves a whole layer's pool (a copy, or a dynamic-slice
+    # or dynamic-update-slice fusion, of [(stack,) pages, KV, page, hd])
+    # carries the kv_pool or kv_scatter scope in its op_name: a device
+    # trace attributes the pool-threading cost by that scope.  Async
+    # copy-start/-done pairs carry no metadata and take no time on the
+    # op line (the transfer runs beside compute).
+    a = cfg.attn
+    pool = rf"\[(\d+,)?{rt.pool_pages + 1},{a.num_kv_heads},16,{a.head_dim}\]"
+    moves = re.compile(rf"^\s*%(copy(?!-start|-done)[.\w-]*|[\w-]*dynamic-"
+                       rf"(update-)?slice_fusion[.\d]*) = \w+{pool}.*$", re.M)
+    found = [m.group(0) for m in moves.finditer(text)]
+    assert found
+    for line in found:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "/kv_pool/" in op_name or "/kv_scatter/" in op_name, line
