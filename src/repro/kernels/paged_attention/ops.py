@@ -20,11 +20,15 @@ decode contract on top of it.
 Contract expected by both paths: ``block_tables`` may be narrower than the
 maximum pages-per-sequence (the runtime buckets the width to the longest
 live sequence so attention cost tracks live tokens, not the seq cap),
-every table entry must be a valid page index, and every query row's
+every table entry up to a lane's last live page (``max(q_positions of
+the lane) // page``) must be a valid page index, and every query row's
 position must map to a key slot whose page holds real data (pad rows are
 given position 0, which reads the lane's first slot — written for any
-live lane — and their output is discarded by the caller).  When the page
-pools are int8, ``k_scales``/``v_scales`` carry the per-page-row
+live lane — and their output is discarded by the caller).  The kernel
+walks each lane only up to that last page and never reads the entries
+past it; the oracle gathers the whole table and masks it, so there
+they must still name pages in range that hold finite values.  When the
+page pools are int8, ``k_scales``/``v_scales`` carry the per-page-row
 dequantization scales ``[P, KV, page]``.
 """
 from __future__ import annotations

@@ -284,13 +284,12 @@ class PagedRuntime:
         if self.kv_quant:
             kwargs = dict(k_scales=pool["k_scale"],
                           v_scales=pool["v_scale"])
-        # each packed row is its own one-row lane of the ragged kernel.
-        # deliberate tradeoff: chunk rows re-gather their lane's pages per
-        # row (O(rows x pages) gather traffic) but the batch carries ZERO
-        # pad rows; the per-lane Q-block form (one Q=chunk lane, decode
-        # lanes padded to Q) amortises the gather but measured ~3x slower
-        # on the CPU oracle because padding dominates — on TPU the Q>1
-        # kernel path is the one to switch to (see ROADMAP)
+        # each packed row is its own one-row lane of the ragged kernel,
+        # which walks that row's pages only up to its own position (a pad
+        # row, at position 0, reads one page).  Chunk rows still re-gather
+        # their lane's pages once per row; the per-lane Q-block form (one
+        # Q=chunk lane, decode lanes at Q=1+k) amortises that gather and
+        # is ROADMAP S2
         with jax.named_scope("attn_kernel"):
             ctx = paged_attention_mixed(q[0][:, None].astype(h.dtype),
                                         pool["k"], pool["v"], block_tables,
@@ -407,7 +406,10 @@ class PagedRuntime:
         ``step.plan``, ``step.pack``, ``step.put``, ``step.compile`` (a
         new bucket only), ``step.device``, ``step.fetch`` (argmax and
         host copy), ``step.commit``; and the counters ``steps``, ``rows``
-        (real token rows) and ``rows_padded`` (rows of the bucket)."""
+        (real token rows), ``rows_padded`` (rows of the bucket),
+        ``attn_pages`` (page slots the attention kernel walks, summed over
+        the bucket's rows, a pad row at one) and ``attn_page_slots``
+        (rows of the bucket x table width)."""
         with self._span("step.plan"):
             log_mark = len(self.sched.preempt_log)
             plan = self.sched.plan()
@@ -435,9 +437,14 @@ class PagedRuntime:
             self._commit(lanes, next_tokens, report)
         tr = self.tracer
         if tr is not None:
+            positions, bts = arrays[1], arrays[3]
+            t, width = bts.shape
             tr.count("steps")
             tr.count("rows", n_rows)
-            tr.count("rows_padded", len(arrays[0]))
+            tr.count("rows_padded", t)
+            tr.count("attn_pages", t + int(
+                np.minimum(positions // self.page, width - 1).sum()))
+            tr.count("attn_page_slots", t * width)
         return report
 
     def _pack(self, decodes, prefills):
@@ -455,7 +462,12 @@ class PagedRuntime:
         t = _bucket_rows(n_rows)
         tokens = np.zeros(t, np.int32)
         positions = np.zeros(t, np.int32)
-        last_rows = np.zeros(_bucket_rows(n_logits), np.int32)
+        # logit rows pad to one per slot, within the row bucket: without
+        # drafts the logits bucket follows the row bucket, so the number
+        # of live lanes adds no executables
+        last_rows = np.zeros(min(t, _bucket_rows(max(n_logits,
+                                                     self.max_slots))),
+                             np.int32)
         lanes: List[tuple] = []
         row_of: List[tuple] = []          # (row_start, n) per lane
         row = 0

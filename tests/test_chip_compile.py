@@ -55,8 +55,23 @@ def _spec(shape, dtype, sharding):
 ])
 def test_paged_kernel_compiles_at_published_widths(one_chip, arch,
                                                     pool_dtype):
+    _compile_kernel(one_chip, arch, pool_dtype, rows=80, width=64,
+                    pages=289)
+
+
+@pytest.mark.parametrize("pool_dtype", ["bfloat16", "int8"])
+def test_clamped_kernel_compiles_at_widest_bench_bucket(one_chip,
+                                                        pool_dtype):
+    """The kernel with its second scalar-prefetch operand (each lane's
+    last live page, which clamps the page index map) at the chat cell's
+    widest fused-step bucket: 96 rows, 64-page tables, 640 pages."""
+    _compile_kernel(one_chip, "stablelm_3b", pool_dtype, rows=96, width=64,
+                    pages=641)
+
+
+def _compile_kernel(one_chip, arch, pool_dtype, *, rows, width, pages):
     a = get_config(arch).attn
-    rows, width, pages, page = 80, 64, 289, 16
+    page = 16
     pool = (pages, a.num_kv_heads, page, a.head_dim)
     args = [_spec((rows, 1, a.num_heads, a.head_dim), "bfloat16", one_chip),
             _spec(pool, pool_dtype, one_chip),

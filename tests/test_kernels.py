@@ -192,6 +192,63 @@ def test_paged_attention_bucketed_width_invariance():
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("case", ["lanes", "pad_lane", "q_block", "int8"])
+def test_paged_attention_mixed_walks_only_live_pages(case):
+    """Each lane walks its table only up to its last live page
+    (``max(position) // page``): pages named past it, in a table wider
+    than any lane needs, are never read, so NaN and +inf there leave the
+    output finite and bit for bit that of the unpoisoned run."""
+    h, kv, hd, page, pps = 4, 2, 64, 16, 8
+    if case == "lanes":        # Q=1 lanes ending on pages 0, 2, 5
+        qpos = [[3], [40], [95]]
+    elif case == "pad_lane":   # a pad lane at position 0 beside a live one
+        qpos = [[0], [70]]
+    elif case == "q_block":    # one lane's rows end on pages 1, 2 and 3
+        qpos = [[30, 31, 32, 33, 47, 48, 63, 0]]
+    else:                      # int8 pools: poisoned pages and scales
+        qpos = [[17, 18], [50, 0], [0, 0]]
+    qpos = np.asarray(qpos, np.int32)
+    b, qn = qpos.shape
+    last = qpos.max(axis=1) // page
+    # lane i's live slots name clean pages; every slot past its last live
+    # page names a page of the poisoned half of the pool
+    npages = 2 * b * pps
+    bt = np.arange(b * pps, dtype=np.int32).reshape(b, pps)
+    dead = np.arange(pps)[None] > last[:, None]
+    bt = np.where(dead, bt + b * pps, bt)
+    q = jnp.asarray(RNG.standard_normal((b, qn, h, hd)), jnp.float32)
+    kp = RNG.standard_normal((npages, kv, page, hd)).astype(np.float32)
+    vp = RNG.standard_normal((npages, kv, page, hd)).astype(np.float32)
+    bad = slice(b * pps, None)
+    kwargs, poisoned = {}, {}
+    if case == "int8":
+        ks = np.abs(kp).max(-1) / 127.0
+        vs = np.abs(vp).max(-1) / 127.0
+        kp = np.round(kp / ks[..., None]).astype(np.int8)
+        vp = np.round(vp / vs[..., None]).astype(np.int8)
+        kwargs = dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
+        ks2, vs2 = ks.copy(), vs.copy()
+        ks2[bad], vs2[bad] = np.nan, np.inf
+        poisoned = dict(k_scales=jnp.asarray(ks2), v_scales=jnp.asarray(vs2))
+        kp2, vp2 = kp.copy(), vp.copy()
+        kp2[bad], vp2[bad] = 127, -127
+    else:
+        kp2, vp2 = kp.copy(), vp.copy()
+        kp2[bad], vp2[bad] = np.nan, np.inf
+        vp2[bad, :, ::2] = np.nan
+    kp, vp, kp2, vp2, bt, qpos = map(jnp.asarray, (kp, vp, kp2, vp2, bt,
+                                                   qpos))
+    clean = paged_attention_mixed(q, kp, vp, bt, qpos, impl="kernel",
+                                  **kwargs)
+    dirty = paged_attention_mixed(q, kp2, vp2, bt, qpos, impl="kernel",
+                                  **(poisoned or kwargs))
+    assert np.isfinite(np.asarray(dirty)).all()
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+    ref = paged_attention_mixed_ref(q, kp, vp, bt, qpos, **kwargs)
+    np.testing.assert_allclose(np.asarray(clean), np.asarray(ref),
+                               rtol=2e-3, atol=2e-3)
+
+
 # ------------------------------------------------------------- sel. scan
 @pytest.mark.parametrize("b,s,d,n,block_d,chunk", [
     (2, 64, 128, 16, 64, 32),

@@ -114,6 +114,22 @@ def test_paged_accounting_during_run():
     assert_no_leaks(eng)
 
 
+def test_logits_bucket_follows_row_bucket():
+    """Without drafts the logit rows pad to one per slot within the row
+    bucket, so however many lanes a step carries, its executable is
+    keyed by (rows, width) alone."""
+    eng = ServingEngine(CFG, max_slots=4, seq_cap=96, page_size=8, seed=0,
+                        backend="paged", chunk_tokens=16, attn_impl="ref")
+    reqs = make_trace(seed=4)
+    for r in reqs:
+        assert eng.submit(r)
+    drain(eng)
+    assert all(r.done for r in reqs)
+    keys = list(eng.runtime.compile_s)
+    assert any(t > 4 for t, _, _ in keys) and any(t < 4 for t, _, _ in keys)
+    assert all(n == min(t, 4) for t, _, n in keys), keys
+
+
 # --------------------------------------------------- fused mixed stepping
 def test_step_token_budget_bounds_every_step():
     """Per-step work never exceeds the fused token budget, and a single

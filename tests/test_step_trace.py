@@ -218,6 +218,44 @@ def test_no_tracer_records_nothing(monkeypatch):
     assert rec.counters["steps"] >= 1
 
 
+@pytest.mark.parametrize("traced", [False, True])
+def test_attn_page_counters_of_one_mixed_step(monkeypatch, traced):
+    """One mixed step, counted by hand (pages of 4, chunks of 8): lane A
+    decodes at position 6 (2 pages), lane B prefills positions 0-7 (four
+    rows at 1 page, four at 2); the 9 rows pad to a 16-row bucket at 1
+    page each and the table is 2 pages wide.  With no tracer attached
+    nothing is counted."""
+    def forbidden(*a, **k):
+        raise AssertionError("counted with no tracer attached")
+
+    if not traced:
+        monkeypatch.setattr(Tracer, "count", forbidden)
+    rng = np.random.default_rng(7)
+    a, b = (Request(req_id=j, tenant="T1", prompt_len=n, max_new_tokens=4,
+                    arrival=0.0,
+                    prompt_tokens=rng.integers(0, CFG.vocab_size, n))
+            for j, n in enumerate((6, 12)))
+    eng = _engine()
+    rec = FlightRecorder() if traced else None
+    eng.tracer = rec
+    assert eng.submit(a)
+    rep = eng.step()
+    eng.finalize_step(rep, 1.0, 0.0)
+    assert rep.kind == "prefill" and a.generated == 1
+    assert eng.submit(b)
+    before = dict(rec.counters) if traced else {}
+    rep = eng.step()
+    eng.finalize_step(rep, 2.0, 1.0)
+    eng.tracer = None
+    assert rep.kind == "mixed" and rep.tokens == 9
+    if not traced:
+        return
+    step = {k: v - before.get(k, 0) for k, v in rec.counters.items()}
+    assert step["rows"] == 9 and step["rows_padded"] == 16
+    assert step["attn_pages"] == 2 + (4 * 1 + 4 * 2) + 7 * 1
+    assert step["attn_page_slots"] == 16 * 2
+
+
 def test_fused_step_hlo_carries_every_scope_name():
     rt = _engine().runtime
     t, w, n = 16, 4, 4
