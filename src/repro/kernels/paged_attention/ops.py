@@ -29,7 +29,15 @@ walks each lane only up to that last page and never reads the entries
 past it; the oracle gathers the whole table and masks it, so there
 they must still name pages in range that hold finite values.  When the
 page pools are int8, ``k_scales``/``v_scales`` carry the per-page-row
-dequantization scales ``[P, KV, page]``.
+dequantization scales ``[P, page, KV]`` (one row of every head's scale
+per token slot; the KV axis may carry pad lanes, never read).
+
+Pools may be one layer's ``[P, KV, page, hd_pool]`` or a stack of
+layers ``[L, P, KV, page, hd_pool]`` (scales ``[L, P, page, KV]``) read
+at the traced ``layer``, so a caller that keeps every layer in one array
+passes it whole and no layer is sliced out.  ``hd_pool`` may exceed the
+head dim of q (a lane-dense pool): the pad lanes must hold zeros, and
+``scale`` defaults to ``1/sqrt`` of q's head dim.
 """
 from __future__ import annotations
 
@@ -69,7 +77,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
 @functools.partial(jax.jit, static_argnames=("scale", "impl", "interpret"))
 def paged_attention_mixed(q, k_pages, v_pages, block_tables, q_positions, *,
-                          scale=None, impl: str = "auto",
+                          layer=0, scale=None, impl: str = "auto",
                           interpret: bool = False,
                           k_scales=None, v_scales=None):
     """q: [B,Q,H,hd]; q_positions: [B,Q] per-row sequence positions."""
@@ -77,10 +85,11 @@ def paged_attention_mixed(q, k_pages, v_pages, block_tables, q_positions, *,
         raise ValueError(f"unknown paged_attention impl {impl!r}")
     if impl == "ref" or (impl == "auto" and not _on_tpu()):
         return paged_attention_mixed_ref(
-            q, k_pages, v_pages, block_tables, q_positions, scale=scale,
-            k_scales=k_scales, v_scales=v_scales)
+            q, k_pages, v_pages, block_tables, q_positions, layer=layer,
+            scale=scale, k_scales=k_scales, v_scales=v_scales)
     return _kernel_mixed(q, k_pages, v_pages, block_tables, q_positions,
-                         scale=scale, interpret=interpret or not _on_tpu(),
+                         layer=layer, scale=scale,
+                         interpret=interpret or not _on_tpu(),
                          k_scales=k_scales, v_scales=v_scales)
 
 

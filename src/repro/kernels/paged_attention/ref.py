@@ -7,11 +7,13 @@ causality is enforced inside the page walk by masking every key slot past
 the row's position.  The classic single-token decode oracle
 (:func:`paged_attention_ref`) is the ``q_len=1`` special case.
 
-Pools are ``[P, KV, page, hd]`` (the KV-head axis ahead of the page
-axis, the layout the TPU kernel tiles).  Pages may optionally be
-int8-quantized with per-page-row scales (``[P, KV, page]``): gathered
-pages are dequantized before the score matmul, so only the pages a lane
-actually touches pay the dequant.
+Pools are ``[P, KV, page, hd_pool]`` (the KV-head axis ahead of the
+page axis, the layout the TPU kernel tiles), or a stack of layers
+``[L, P, KV, page, hd_pool]`` read at ``layer``; lanes past the head dim
+of q are zero pad and are dropped.  Pages may optionally be
+int8-quantized with per-page-row scales (``[(L,) P, page, KV]``, the KV
+axis possibly padded): gathered pages are dequantized before the score
+matmul, so only the pages a lane actually touches pay the dequant.
 """
 from __future__ import annotations
 
@@ -25,28 +27,39 @@ def _gather_pages(pages, block_tables, scales, out_dtype):
     b, pps = block_tables.shape
     kv, page, hd = pages.shape[1], pages.shape[2], pages.shape[3]
     g = pages[block_tables].astype(jnp.float32)  # [B, PPS, KV, page, hd]
-    if scales is not None:
-        g = g * scales[block_tables].astype(jnp.float32)[..., None]
+    if scales is not None:                       # [P, page, KV(+pad)]
+        sc = scales[block_tables][..., :kv].swapaxes(-1, -2)
+        g = g * sc.astype(jnp.float32)[..., None]
     g = g.transpose(0, 1, 3, 2, 4).reshape(b, pps * page, kv, hd)
     return g.astype(out_dtype)
 
 
 def paged_attention_mixed_ref(q, k_pages, v_pages, block_tables, q_positions,
-                              *, scale=None, k_scales=None, v_scales=None):
+                              *, layer=0, scale=None, k_scales=None,
+                              v_scales=None):
     """Ragged multi-row attention over a paged KV cache.
 
     q            [B, Q, H, hd]      (Q query rows per lane; pad rows are
                                      harmless — give them position 0)
-    k_pages      [P, KV, page, hd]  (global page pool; int8 if *_scales)
-    v_pages      [P, KV, page, hd]
+    k_pages      [P, KV, page, hd_pool]  (global page pool; int8 if
+                                     *_scales), or [L, P, ...] read at
+                                     ``layer``; hd_pool >= hd
+    v_pages      [P, KV, page, hd_pool]
     block_tables [B, PPS] int32     (page ids per sequence)
     q_positions  [B, Q] int32       (sequence position of each query row;
                                      row i attends key slots t <= pos[i])
-    k_scales     [P, KV, page] f32  (optional int8 per-page-row scales)
-    v_scales     [P, KV, page] f32
+    k_scales     [P, page, KV] f32  (optional int8 per-page-row scales;
+                                     [L, P, page, KV] with a stack; KV
+                                     may carry pad lanes)
+    v_scales     [P, page, KV] f32
     Returns      [B, Q, H, hd]
     """
+    if k_pages.ndim == 5:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[layer], v_scales[layer]
     b, qn, h, hd = q.shape
+    k_pages, v_pages = k_pages[..., :hd], v_pages[..., :hd]
     kv = k_pages.shape[1]
     g = h // kv
     if scale is None:

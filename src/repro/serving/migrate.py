@@ -51,6 +51,15 @@ def _pool_leaves(pools) -> Iterator[Tuple[str, str, str, str]]:
                 yield f"{group}/{name}/{fld}", group, name, fld
 
 
+def _shipped_lanes(rt, fld: str) -> int:
+    """The lanes of a pool leaf that carry data: every leaf is a stack of
+    layers whose last axis the runtime pads to whole 128-lane tiles (K/V
+    past the head dim, the int8 scales past the KV heads).  Only these
+    lanes ship; the destination's pad lanes stay zero."""
+    a = rt.cfg.attn
+    return a.num_kv_heads if fld.endswith("_scale") else a.head_dim
+
+
 def _page_digest(prev: bytes, tokens: Tuple[int, ...],
                  payload: Dict[str, np.ndarray]) -> bytes:
     """Chained per-page hash: ties this page's KV bytes to the page's
@@ -159,11 +168,9 @@ class PageExporter:
             toks = tuple(int(t) for t in hist[p * ps:(p + 1) * ps])
             payload: Dict[str, np.ndarray] = {}
             for key, group, name, fld in _pool_leaves(self.rt.pools):
-                pool = self.rt.pools[group][name][fld]
-                if group == "period":                 # stacked [repeats,...]
-                    payload[key] = np.asarray(pool[:, page])
-                else:
-                    payload[key] = np.asarray(pool[page])
+                pool = self.rt.pools[group][name][fld]   # [layers, P, ...]
+                payload[key] = np.asarray(
+                    pool[:, page, ..., :_shipped_lanes(self.rt, fld)])
             prev = _page_digest(prev, toks, payload)
             man.pages.append(PageRecord(src_page=page, tokens=toks,
                                         payload=payload, digest=prev))
@@ -232,10 +239,8 @@ class PageImporter:
             if arr is None:
                 raise ImportReject(f"payload leaf {key} missing")
             pool = pools[group][name][fld]
-            if group == "period":
-                pools[group][name][fld] = pool.at[:, dst_page].set(arr)
-            else:
-                pools[group][name][fld] = pool.at[dst_page].set(arr)
+            pools[group][name][fld] = pool.at[
+                :, dst_page, ..., :_shipped_lanes(self.rt, fld)].set(arr)
 
     def import_lane(self, man: LaneManifest) -> bool:
         """Verify-then-commit one lane.  True iff the lane is now

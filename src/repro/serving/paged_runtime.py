@@ -6,9 +6,9 @@ fixed pool of ``page_size``-token pages (plus one trash page for masked
 lanes) and addresses it through per-sequence block tables owned by
 ``PagedKVCache``.  Decode memory therefore scales with *live tokens*, the
 pool can be overcommitted (admission never reserves prompt+max_new up
-front), and the block-table width handed to the attention kernel is
-bucketed to the longest live sequence, so per-step attention cost tracks
-live context rather than ``max_slots x seq_cap``.
+front), and the attention kernel walks each row's pages only up to its
+own position, so per-step attention cost tracks live context rather than
+``max_slots x seq_cap``.
 
 ONE forward pass, pure and jitted — the **fused mixed step**: the batch
 is the FLATTENED token stream of the step (the vLLM ragged-batch layout):
@@ -25,8 +25,10 @@ prompt never stalls the decode lanes — it only consumes the prefill share
 of the step budget — which is what keeps ITL tails flat under admission
 churn; and because the batch is packed, step cost tracks REAL tokens
 (8 decodes + a 64-token chunk cost ~72 rows, not lanes x max-chunk
-padding).  Row counts are bucketed (pow2 then /16 granules) so the jit
-shape set stays bounded; pad rows write to the trash page and carry
+padding).  Row counts are bucketed (pow2 then /16 granules) and every
+block table is handed over at its full width (the kernel's page walk
+stops at the step's longest live row), so the jit shape set is one
+executable per row bucket; pad rows write to the trash page and carry
 position 0, so they read one valid slot and their output is discarded.
 
 Page pools may be int8 (``kv_dtype="int8"``): K/V rows are quantized
@@ -71,8 +73,7 @@ from repro.models.model import _apply_ffn, _logits, embed_tokens
 from repro.serving.engine import StepReport
 from repro.serving.kvcache import PagedKVCache
 from repro.serving.request import EXCEEDS_SEQ_CAP, Request, SubmitOutcome
-from repro.serving.sched import (PagedScheduler, SchedConfig, bucket_rows,
-                                 next_pow2)
+from repro.serving.sched import PagedScheduler, SchedConfig, bucket_rows
 
 
 def paged_unsupported_reason(cfg: ModelConfig) -> Optional[str]:
@@ -93,9 +94,8 @@ def paged_unsupported_reason(cfg: ModelConfig) -> Optional[str]:
     return None
 
 
-# row/width bucketing lives in serving/sched.py (the draft planner is
+# row bucketing lives in serving/sched.py (the draft planner is
 # bucket-aware: rows riding the padding are funded at zero budget cost)
-_next_pow2 = next_pow2
 _bucket_rows = bucket_rows
 
 
@@ -153,7 +153,8 @@ class PagedRuntime:
         # (without aliasing every step would copy the whole page pool,
         # making step cost O(pool) instead of O(live tokens))
         self._mixed_fn = jax.jit(self._mixed_impl, donate_argnums=(1,))
-        # executable cache per (rows, width) bucket: the fused step has
+        # executable cache per (rows, width, logit-rows) bucket, the width
+        # always the full table (``pps``): the fused step has
         # more shape buckets than the old split prefill/decode passes, so
         # each bucket is AOT-compiled on first sight OUTSIDE the timed
         # region (production runtimes precompile their bucket grid at
@@ -169,26 +170,36 @@ class PagedRuntime:
 
     # ------------------------------------------------------------- pools
     def _init_pools(self) -> Dict[str, Any]:
+        """Every layer's K and V pool, stacked per layer group:
+        ``[L, P+1, KV, page, hd_pool]`` (a prefix layer is a stack of one)
+        with ``hd_pool`` the head dim rounded up to whole 128-lane tiles
+        and the pad lanes zero.  That is the layout the TPU kernel reads
+        in place (see kernels/paged_attention), and one a layer's new rows
+        are written into as rows of a flat ``[L*(P+1)*KV*page, hd_pool]``
+        view: with the head dim on whole lane tiles and ``page`` on whole
+        sublane tiles the view is a bitcast, so no step relays a pool
+        out.  int8 pools keep per-row scales ``[L, P+1, page, KV_pool]``,
+        a token's heads in one row, ``KV_pool`` rounded up the same way."""
         a = self.cfg.attn
         dt = jnp.int8 if self.kv_quant else jnp.dtype(self.cfg.dtype)
-        # [P, KV, page, hd]: the head axis ahead of the page axis is the
-        # layout the TPU kernel tiles (see kernels/paged_attention)
-        shape = (self.pool_pages + 1, a.num_kv_heads, self.page, a.head_dim)
-        sshape = (self.pool_pages + 1, a.num_kv_heads, self.page)
+        lanes = lambda n: -(-n // 128) * 128
+        shape = (self.pool_pages + 1, a.num_kv_heads, self.page,
+                 lanes(a.head_dim))
+        sshape = (self.pool_pages + 1, self.page, lanes(a.num_kv_heads))
 
-        def pool(stack: int = 0):
-            s = (stack,) + shape if stack else shape
-            d = {"k": jnp.zeros(s, dt, device=self.device),
-                 "v": jnp.zeros(s, dt, device=self.device)}
+        def pool(stack: int):
+            d = {"k": jnp.zeros((stack,) + shape, dt, device=self.device),
+                 "v": jnp.zeros((stack,) + shape, dt, device=self.device)}
             if self.kv_quant:
-                ss = (stack,) + sshape if stack else sshape
-                d["k_scale"] = jnp.zeros(ss, jnp.float32, device=self.device)
-                d["v_scale"] = jnp.zeros(ss, jnp.float32, device=self.device)
+                d["k_scale"] = jnp.zeros((stack,) + sshape, jnp.float32,
+                                         device=self.device)
+                d["v_scale"] = jnp.zeros((stack,) + sshape, jnp.float32,
+                                         device=self.device)
             return d
 
         pools: Dict[str, Any] = {}
         if self.cfg.prefix:
-            pools["prefix"] = {f"layer{i}": pool()
+            pools["prefix"] = {f"layer{i}": pool(1)
                                for i in range(len(self.cfg.prefix))}
         if self.cfg.period:
             pools["period"] = {f"sub{i}": pool(self.cfg.repeats)
@@ -197,33 +208,41 @@ class PagedRuntime:
 
     # ------------------------------------------------------- forward: shared
     def _scatter(self, pool, k, v, page_ids, offs):
-        """Write the K/V rows of every valid (lane, row) into the page
-        pools (masked rows land on the trash page).  int8 pools quantize
-        per-row and store the scales beside the pages."""
-        if not self.kv_quant:
-            return {**pool,
-                    "k": pool["k"].at[page_ids, :, offs].set(
-                        k.astype(pool["k"].dtype)),
-                    "v": pool["v"].at[page_ids, :, offs].set(
-                        v.astype(pool["v"].dtype))}
-        kq, ks = attn_mod._quantize_kv(k)
-        vq, vs = attn_mod._quantize_kv(v)
-        return {**pool,
-                "k": pool["k"].at[page_ids, :, offs].set(kq),
-                "v": pool["v"].at[page_ids, :, offs].set(vq),
-                "k_scale": pool["k_scale"].at[page_ids, :, offs].set(
-                    ks.astype(jnp.float32)),
-                "v_scale": pool["v_scale"].at[page_ids, :, offs].set(
-                    vs.astype(jnp.float32))}
+        """Write the K/V rows ``[T, KV, hd]`` into the stacked pools in
+        place, at ``page_ids`` counted along the stack's pages (layer
+        ``l``'s page ``p`` is ``l*(P+1) + p``; masked rows land on a
+        trash page): one row write per leaf into its flat view, K/V at
+        row ``(page*KV + head)*page_size + off``, the int8 scales (all
+        heads in one row) at ``page*page_size + off``, each row
+        zero-padded to the pool's lanes.  int8 pools quantize per row."""
+        kv_heads = k.shape[1]
+        slots = page_ids * self.page + offs
+        rows = ((page_ids[:, None] * kv_heads
+                 + jnp.arange(kv_heads, dtype=jnp.int32)) * self.page
+                + offs[:, None]).reshape(-1)
+        scales = {}
+        if self.kv_quant:
+            k, scales["k_scale"] = attn_mod._quantize_kv(k)
+            v, scales["v_scale"] = attn_mod._quantize_kv(v)
+        writes = [("k", rows, k), ("v", rows, v)] + [
+            (key, slots, x) for key, x in scales.items()]
+        new = dict(pool)
+        for key, at, x in writes:
+            stack = pool[key]
+            x = x.reshape(at.shape[0], -1).astype(stack.dtype)
+            x = jnp.pad(x, ((0, 0), (0, stack.shape[-1] - x.shape[-1])))
+            flat = stack.reshape(-1, stack.shape[-1])
+            new[key] = flat.at[at].set(x).reshape(stack.shape)
+        return new
 
     def _walk_layers(self, params, pools, h, layer_fn):
-        """Run ``layer_fn(lp, h, layer, pool) -> (h, pool)`` over the
-        prefix layers and the scanned period stack, threading each layer's
-        page-pool dict through (the stacked period pools are
-        indexed/updated per scan step, mirroring the dense decode path).
-        Named scopes: ``kv_pool`` on the per-layer pool slice and its
-        write-back, ``layer_weights`` on the scan, whose body slices each
-        layer's weights out of the stack."""
+        """Run ``layer_fn(lp, h, layer, pool, idx) -> (h, pool)`` over the
+        prefix layers and the scanned period stack, where ``pool`` is the
+        layer group's stacked pool dict and ``idx`` the layer's index in
+        it: the stack is the scan carry, written and read in place, and
+        no layer's pool is sliced out.  Named scope ``layer_weights`` on
+        the scan, whose body slices each layer's weights out of the
+        stack."""
         cfg = self.cfg
         new_pools = dict(pools)
         if cfg.prefix:
@@ -231,7 +250,7 @@ class PagedRuntime:
             for i, layer in enumerate(cfg.prefix):
                 key = f"layer{i}"
                 h, p = layer_fn(params["prefix"][key], h, layer,
-                                pools["prefix"][key])
+                                pools["prefix"][key], 0)
                 new_pools["prefix"][key] = p
         if cfg.period:
             def body(carry, xs):
@@ -239,16 +258,9 @@ class PagedRuntime:
                 lp_stack, idx = xs
                 for i, layer in enumerate(cfg.period):
                     sub = f"sub{i}"
-                    with jax.named_scope("kv_pool"):
-                        pool_i = {key: jax.lax.dynamic_index_in_dim(
-                            pp[sub][key], idx, 0, keepdims=False)
-                            for key in pp[sub]}
-                    hh, pool_i = layer_fn(lp_stack[sub], hh, layer, pool_i)
-                    with jax.named_scope("kv_pool"):
-                        pp = {**pp, sub: {
-                            key: jax.lax.dynamic_update_index_in_dim(
-                                pp[sub][key], pool_i[key], idx, 0)
-                            for key in pp[sub]}}
+                    hh, pool_i = layer_fn(lp_stack[sub], hh, layer, pp[sub],
+                                          idx)
+                    pp = {**pp, sub: pool_i}
                 return (hh, pp), ()
 
             idxs = jnp.arange(cfg.repeats, dtype=jnp.int32)
@@ -260,7 +272,7 @@ class PagedRuntime:
 
     # ------------------------------------------------ forward: fused mixed
     def _mixed_layer(self, lp, h, layer: LayerSpec, positions, qpos,
-                     page_ids, offs, block_tables, pool):
+                     page_ids, offs, block_tables, pool, idx):
         """One GQA layer over the flattened fused batch: ``h`` is
         [1, T, d] packed token rows, KV via the page pool, causality via
         per-row positions inside the page walk.  Mirrors
@@ -279,7 +291,9 @@ class PagedRuntime:
             q = shard(apply_rope(q, positions, cfg.rope_theta), policy.heads)
             k = apply_rope(k, positions, cfg.rope_theta)
         with jax.named_scope("kv_scatter"):
-            pool = self._scatter(pool, k[0], v[0], page_ids, offs)
+            pool = self._scatter(pool, k[0], v[0],
+                                 idx * (self.pool_pages + 1) + page_ids,
+                                 offs)
         kwargs = {}
         if self.kv_quant:
             kwargs = dict(k_scales=pool["k_scale"],
@@ -293,7 +307,8 @@ class PagedRuntime:
         with jax.named_scope("attn_kernel"):
             ctx = paged_attention_mixed(q[0][:, None].astype(h.dtype),
                                         pool["k"], pool["v"], block_tables,
-                                        qpos[:, None], impl=self.attn_impl,
+                                        qpos[:, None], layer=idx,
+                                        impl=self.attn_impl,
                                         **kwargs)             # [T, 1, H, hd]
         with jax.named_scope("attn_out"):
             out = jnp.einsum("bshk,hkd->bsd",
@@ -326,9 +341,9 @@ class PagedRuntime:
             h = embed_tokens(params, cfg, tokens[None], policy)
         h, new_pools = self._walk_layers(
             params, pools, h,
-            lambda lp, hh, layer, pool: self._mixed_layer(
+            lambda lp, hh, layer, pool, idx: self._mixed_layer(
                 lp, hh, layer, positions2, qpos, page_ids, offs,
-                block_tables, pool))
+                block_tables, pool, idx))
         with jax.named_scope("logits"):
             h = rms_norm(h, params["final_norm"], cfg.norm_eps)
             h_last = h[0][last_rows][None]               # [1, L, d]
@@ -409,7 +424,8 @@ class PagedRuntime:
         (real token rows), ``rows_padded`` (rows of the bucket),
         ``attn_pages`` (page slots the attention kernel walks, summed over
         the bucket's rows, a pad row at one) and ``attn_page_slots``
-        (rows of the bucket x table width)."""
+        (rows of the bucket x the page steps the kernel's walk takes, the
+        longest row's live pages)."""
         with self._span("step.plan"):
             log_mark = len(self.sched.preempt_log)
             plan = self.sched.plan()
@@ -439,19 +455,21 @@ class PagedRuntime:
         if tr is not None:
             positions, bts = arrays[1], arrays[3]
             t, width = bts.shape
+            slots = np.minimum(positions // self.page, width - 1)
             tr.count("steps")
             tr.count("rows", n_rows)
             tr.count("rows_padded", t)
-            tr.count("attn_pages", t + int(
-                np.minimum(positions // self.page, width - 1).sum()))
-            tr.count("attn_page_slots", t * width)
+            tr.count("attn_pages", t + int(slots.sum()))
+            tr.count("attn_page_slots", t * (int(slots.max()) + 1))
         return report
 
     def _pack(self, decodes, prefills):
         """Pack the step's real tokens back to back: 1+len(draft) rows per
         decode lane (the base feedback token plus its speculative verify
         rows), ``clen`` rows per prefill chunk — cost tracks live tokens,
-        and the row/width/logit buckets keep the jit shape set bounded.
+        and the row/logit buckets keep the jit shape set bounded: every
+        block table is ``pps`` wide, and the kernel walks none of it past
+        the step's longest live row.
         Returns (real rows, (tokens, positions, n_rows, block tables,
         last rows), lanes)."""
         n_rows = sum(1 + len(s.draft) for s in decodes) \
@@ -472,7 +490,6 @@ class PagedRuntime:
         row_of: List[tuple] = []          # (row_start, n) per lane
         row = 0
         li = 0                            # next logit-row slot
-        max_pages = 1
         for s in decodes:
             q = 1 + len(s.draft)          # verify q_len for this lane
             lanes.append(("d", s, li, q))
@@ -485,7 +502,6 @@ class PagedRuntime:
             li += q
             row_of.append((row, q))
             row += q
-            max_pages = max(max_pages, self.kv.pages_needed(pos + q))
         for s, start, clen in prefills:
             lanes.append(("p", s, start, clen, li))
             tokens[row:row + clen] = np.asarray(
@@ -496,11 +512,10 @@ class PagedRuntime:
             li += 1
             row_of.append((row, clen))
             row += clen
-            max_pages = max(max_pages, self.kv.pages_needed(start + clen))
-        width = min(self.pps, _next_pow2(max_pages))
-        bts = np.zeros((t, width), np.int32)
+        bts = np.zeros((t, self.pps), np.int32)
         for (r0, n), lane in zip(row_of, lanes):
-            bts[r0:r0 + n] = self.kv.block_table(lane[1].req.req_id, width)
+            bts[r0:r0 + n] = self.kv.block_table(lane[1].req.req_id,
+                                                 self.pps)
         return n_rows, (tokens, positions, np.int32(n_rows), bts,
                         last_rows), lanes
 

@@ -63,70 +63,103 @@ def test_paged_kernel_compiles_at_published_widths(one_chip, arch,
 def test_clamped_kernel_compiles_at_widest_bench_bucket(one_chip,
                                                         pool_dtype):
     """The kernel with its second scalar-prefetch operand (each lane's
-    last live page, which clamps the page index map) at the chat cell's
-    widest fused-step bucket: 96 rows, 64-page tables, 640 pages."""
-    _compile_kernel(one_chip, "stablelm_3b", pool_dtype, rows=96, width=64,
+    last live page, which clamps the page index map) and its dynamic page
+    walk at the chat cell's widest fused-step bucket: 96 rows, tables at
+    the full 128-page width (seq_cap 2048), 640 pages."""
+    _compile_kernel(one_chip, "stablelm_3b", pool_dtype, rows=96, width=128,
                     pages=641)
 
 
-def _compile_kernel(one_chip, arch, pool_dtype, *, rows, width, pages):
+def _compile_kernel(one_chip, arch, pool_dtype, *, rows, width, pages,
+                    layers=4):
+    """The kernel on a stack of ``layers`` lane-dense pools, read at a
+    traced layer index, as the fused step calls it."""
     a = get_config(arch).attn
     page = 16
-    pool = (pages, a.num_kv_heads, page, a.head_dim)
+    lanes = lambda n: -(-n // 128) * 128
+    pool = (layers, pages, a.num_kv_heads, page, lanes(a.head_dim))
     args = [_spec((rows, 1, a.num_heads, a.head_dim), "bfloat16", one_chip),
             _spec(pool, pool_dtype, one_chip),
             _spec(pool, pool_dtype, one_chip),
             _spec((rows, width), "int32", one_chip),
-            _spec((rows, 1), "int32", one_chip)]
+            _spec((rows, 1), "int32", one_chip),
+            _spec((), "int32", one_chip)]
     if pool_dtype == "int8":
-        scales = _spec(pool[:3], "float32", one_chip)
+        scales = _spec((layers, pages, page, lanes(a.num_kv_heads)),
+                       "float32", one_chip)
         args += [scales, scales]
 
-        def fn(q, k, v, bt, qpos, ks, vs):
-            return paged_attention_mixed(q, k, v, bt, qpos, k_scales=ks,
-                                         v_scales=vs)
+        def fn(q, k, v, bt, qpos, layer, ks, vs):
+            return paged_attention_mixed(q, k, v, bt, qpos, layer=layer,
+                                         k_scales=ks, v_scales=vs)
     else:
-        fn = paged_attention_mixed
+        def fn(q, k, v, bt, qpos, layer):
+            return paged_attention_mixed(q, k, v, bt, qpos, layer=layer)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_step_compiles_at_published_widths(one_chip, monkeypatch):
-    """One fused mixed step of stablelm_3b at published widths, depth cut
-    to 2 layers, with the Pallas kernel in it and the KV pools donated."""
-    # the kernel path is chosen by asking for the default backend, which
-    # is the CPU here: steer it to the chip's branch for this compile
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    cfg = dataclasses.replace(get_config("stablelm_3b"), repeats=2)
-    rt = PagedRuntime(cfg, None, max_slots=8, seq_cap=1024, page_size=16,
-                      pool_pages=64)
+def _compile_step(one_chip, cfg, kv_dtype, pages):
+    rt = PagedRuntime(cfg, None, max_slots=32, seq_cap=2048, page_size=16,
+                      pool_pages=pages, kv_dtype=kv_dtype)
     place = lambda t: jax.tree.map(
         lambda x: _spec(x.shape, x.dtype, one_chip), t)
-    rows, width, logits = 80, 64, 16
+    rows, width, logits = 96, rt.pps, 32
     compiled = rt._mixed_fn.lower(
         place(Model(cfg).param_specs()), place(rt.pools),
         _spec((rows,), "int32", one_chip), _spec((rows,), "int32", one_chip),
         _spec((), "int32", one_chip), _spec((rows, width), "int32", one_chip),
         _spec((logits,), "int32", one_chip)).compile()
+    return rt, compiled
+
+
+# (arch, kv pool dtype, whether the whole step's temporaries fit under one
+# layer's K pool): head_dim 80, padded to 128 lanes in the pools, and
+# head_dim 128, unpadded, whose 4096-wide FFN weights the step relays out
+# per layer (a layer_weights cost of ~180 MB that no pool op adds to)
+@pytest.mark.parametrize("arch,kv_dtype,temp_under_layer_pool", [
+    ("stablelm_3b", "auto", True),
+    ("stablelm_3b", "int8", True),
+    ("olmo2_7b", "auto", False),
+])
+def test_fused_step_compiles_at_published_widths(one_chip, monkeypatch,
+                                                 arch, kv_dtype,
+                                                 temp_under_layer_pool):
+    """One fused mixed step at published widths, depth cut to 2 layers,
+    at the chat cell's pool (640 pages) and widest bucket, with the
+    Pallas kernel in it and the KV pools donated.  The pools are written
+    and read in place: no op copies or dynamic-(update-)slices a layer's
+    pool or the stack (or their flat row views), and the step's
+    temporaries do not grow with the pool."""
+    # the kernel path is chosen by asking for the default backend, which
+    # is the CPU here: steer it to the chip's branch for this compile
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config(arch), repeats=2)
+    rt, compiled = _compile_step(one_chip, cfg, kv_dtype, 640)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     mem = compiled.memory_analysis()
+    leaves = jax.tree.leaves(rt.pools)
     # the donated pools alias the returned pools: no second pool copy
-    pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                     for x in jax.tree.leaves(rt.pools))
-    assert mem.alias_size_in_bytes >= pool_bytes
-    # every op that moves a whole layer's pool (a copy, or a dynamic-slice
-    # or dynamic-update-slice fusion, of [(stack,) pages, KV, page, hd])
-    # carries the kv_pool or kv_scatter scope in its op_name: a device
-    # trace attributes the pool-threading cost by that scope.  Async
-    # copy-start/-done pairs carry no metadata and take no time on the
-    # op line (the transfer runs beside compute).
-    a = cfg.attn
-    pool = rf"\[(\d+,)?{rt.pool_pages + 1},{a.num_kv_heads},16,{a.head_dim}\]"
-    moves = re.compile(rf"^\s*%(copy(?!-start|-done)[.\w-]*|[\w-]*dynamic-"
-                       rf"(update-)?slice_fusion[.\d]*) = \w+{pool}.*$", re.M)
-    found = [m.group(0) for m in moves.finditer(text)]
-    assert found
-    for line in found:
-        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
-        assert "/kv_pool/" in op_name or "/kv_scatter/" in op_name, line
+    assert mem.alias_size_in_bytes >= sum(x.nbytes for x in leaves)
+    layer_k = rt.pools["period"]["sub0"]["k"].nbytes // cfg.repeats
+    _, small = _compile_step(one_chip, cfg, kv_dtype, 64)
+    grown = mem.temp_size_in_bytes - small.memory_analysis().temp_size_in_bytes
+    assert grown < layer_k // 16
+    if temp_under_layer_pool:
+        assert mem.temp_size_in_bytes < layer_k
+    # a layer's pool, the stack, and the flat row views of either
+    shapes = set()
+    for x in leaves:
+        for s in (x.shape, x.shape[1:], (1,) + x.shape[1:]):
+            shapes.add(",".join(map(str, s)))
+            shapes.add(f"{int(np.prod(s[:-1]))},{s[-1]}")
+    op = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = \w+\[([\d,]*)\]\S* "
+                    r"([\w-]+)\(", re.M)
+    moves = [m.group(0) for m in op.finditer(text)
+             if m.group(2) in shapes
+             and (m.group(3) in ("copy", "dynamic-slice",
+                                 "dynamic-update-slice")
+                  or "dynamic-slice" in m.group(1)
+                  or "dynamic-update-slice" in m.group(1))]
+    assert not moves, moves

@@ -166,7 +166,9 @@ def test_paged_attention_int8_pages_close(impl):
     def quant(p):
         s = np.abs(np.asarray(p)).max(-1) / 127.0 + 1e-8
         iv = np.clip(np.round(np.asarray(p) / s[..., None]), -127, 127)
-        return jnp.asarray(iv.astype(np.int8)), jnp.asarray(s, jnp.float32)
+        # scales ride [P, page, KV]: one row of every head's per token
+        return (jnp.asarray(iv.astype(np.int8)),
+                jnp.asarray(s.swapaxes(1, 2), jnp.float32))
 
     kq, ks = quant(kp)
     vq, vs = quant(vp)
@@ -192,12 +194,14 @@ def test_paged_attention_bucketed_width_invariance():
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("case", ["lanes", "pad_lane", "q_block", "int8"])
+@pytest.mark.parametrize("case", ["lanes", "pad_lane", "q_block", "int8",
+                                  "short_walk"])
 def test_paged_attention_mixed_walks_only_live_pages(case):
     """Each lane walks its table only up to its last live page
-    (``max(position) // page``): pages named past it, in a table wider
-    than any lane needs, are never read, so NaN and +inf there leave the
-    output finite and bit for bit that of the unpoisoned run."""
+    (``max(position) // page``), and the grid's page axis only up to the
+    longest lane's: pages named past it, in a table wider than any lane
+    needs, are never read, so NaN and +inf there leave the output finite
+    and bit for bit that of the unpoisoned run."""
     h, kv, hd, page, pps = 4, 2, 64, 16, 8
     if case == "lanes":        # Q=1 lanes ending on pages 0, 2, 5
         qpos = [[3], [40], [95]]
@@ -205,6 +209,8 @@ def test_paged_attention_mixed_walks_only_live_pages(case):
         qpos = [[0], [70]]
     elif case == "q_block":    # one lane's rows end on pages 1, 2 and 3
         qpos = [[30, 31, 32, 33, 47, 48, 63, 0]]
+    elif case == "short_walk":  # every lane within 2 of the table's 8 pages
+        qpos = [[5], [20], [0]]
     else:                      # int8 pools: poisoned pages and scales
         qpos = [[17, 18], [50, 0], [0, 0]]
     qpos = np.asarray(qpos, np.int32)
@@ -226,6 +232,7 @@ def test_paged_attention_mixed_walks_only_live_pages(case):
         vs = np.abs(vp).max(-1) / 127.0
         kp = np.round(kp / ks[..., None]).astype(np.int8)
         vp = np.round(vp / vs[..., None]).astype(np.int8)
+        ks, vs = ks.swapaxes(1, 2), vs.swapaxes(1, 2)   # [P, page, KV]
         kwargs = dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
         ks2, vs2 = ks.copy(), vs.copy()
         ks2[bad], vs2[bad] = np.nan, np.inf
@@ -247,6 +254,56 @@ def test_paged_attention_mixed_walks_only_live_pages(case):
     ref = paged_attention_mixed_ref(q, kp, vp, bt, qpos, **kwargs)
     np.testing.assert_allclose(np.asarray(clean), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("hd", [80, 128])
+@pytest.mark.parametrize("pool_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_attention_stacked_pool_matches_unstacked(hd, pool_dtype,
+                                                        layer):
+    """A stack of three layers' lane-dense pools ``[L, P, KV, page,
+    hd_pool]`` (``hd_pool`` = ``hd`` rounded up to 128 lanes, zero past
+    ``hd``; int8 scales ``[L, P, page, 128]``), read at a traced layer
+    index, gives the kernel's outputs for that layer's unstacked,
+    unpadded pages ``[P, KV, page, hd]``, and the oracle's."""
+    nl, b, qn, h, kv, page, pps, npages = 3, 3, 2, 4, 2, 16, 4, 10
+    hd_pool = -(-hd // 128) * 128
+    q = jnp.asarray(RNG.standard_normal((b, qn, h, hd)), jnp.bfloat16)
+    kp = RNG.standard_normal((nl, npages, kv, page, hd)).astype(np.float32)
+    vp = RNG.standard_normal((nl, npages, kv, page, hd)).astype(np.float32)
+    bt = jnp.asarray(RNG.integers(0, npages, (b, pps)), jnp.int32)
+    qpos = jnp.asarray(RNG.integers(0, pps * page, (b, qn)), jnp.int32)
+    one, stack = {}, {}
+    if pool_dtype == "int8":
+        ks = np.abs(kp).max(-1) / 127.0 + 1e-8         # [L, P, KV, page]
+        vs = np.abs(vp).max(-1) / 127.0 + 1e-8
+        kp = np.round(kp / ks[..., None]).astype(np.int8)
+        vp = np.round(vp / vs[..., None]).astype(np.int8)
+        ks, vs = ks.swapaxes(2, 3), vs.swapaxes(2, 3)  # [L, P, page, KV]
+        one = dict(k_scales=jnp.asarray(ks[layer]),
+                   v_scales=jnp.asarray(vs[layer]))
+        lanes = ((0, 0),) * 3 + ((0, 128 - kv),)
+        stack = dict(k_scales=jnp.asarray(np.pad(ks, lanes)),
+                     v_scales=jnp.asarray(np.pad(vs, lanes)))
+    else:
+        kp = kp.astype(jnp.bfloat16)
+        vp = vp.astype(jnp.bfloat16)
+    lanes = ((0, 0),) * 4 + ((0, hd_pool - hd),)
+    kst, vst = jnp.asarray(np.pad(kp, lanes)), jnp.asarray(np.pad(vp, lanes))
+    unstacked = paged_attention_mixed(q, jnp.asarray(kp[layer]),
+                                      jnp.asarray(vp[layer]), bt, qpos,
+                                      impl="kernel", **one)
+    stacked = paged_attention_mixed(q, kst, vst, bt, qpos,
+                                    layer=jnp.int32(layer), impl="kernel",
+                                    **stack)
+    ref = paged_attention_mixed(q, kst, vst, bt, qpos,
+                                layer=jnp.int32(layer), impl="ref", **stack)
+    assert stacked.shape == (b, qn, h, hd)
+    # the pad lanes add exact zeros: the same outputs bit for bit
+    np.testing.assert_array_equal(np.asarray(stacked), np.asarray(unstacked))
+    np.testing.assert_allclose(np.asarray(stacked, np.float32),
+                               np.asarray(ref, np.float32), rtol=2e-2,
+                               atol=2e-2)
 
 
 # ------------------------------------------------------------- sel. scan

@@ -7,6 +7,7 @@ the ledger's per-root fabric demand, and the gateway's OpenMetrics
 exemplars parse back to the slowest retained request per bucket.
 """
 import copy
+import dataclasses
 import re
 
 import numpy as np
@@ -59,18 +60,30 @@ def reference_tokens(prompts, max_new=6):
 
 
 def dst_pool_payload(rt, pages):
-    """Read back one page's leaves from a runtime's pools, in the same
-    leaf-key layout the exporter serializes."""
+    """Read back one page's leaves from a runtime's pools (each a stack
+    of layers), in the same leaf-key layout the exporter serializes,
+    with every lane: the shipped ones and the zero pad lanes past them."""
     from repro.serving.migrate import _pool_leaves
     out = []
     for page in pages:
         payload = {}
         for key, group, name, fld in _pool_leaves(rt.pools):
-            pool = rt.pools[group][name][fld]
-            payload[key] = np.asarray(pool[:, page] if group == "period"
-                                      else pool[page])
+            payload[key] = np.asarray(rt.pools[group][name][fld][:, page])
         out.append(payload)
     return out
+
+
+def assert_landed(rec, landed):
+    """A shipped page landed byte for byte in its lanes, with zeros in
+    the pool's pad lanes, and its chain link recomputes on the landed
+    bytes.  Returns the landed payload as shipped."""
+    shipped = {}
+    for key, arr in rec.payload.items():
+        width = np.asarray(arr).shape[-1]
+        assert np.array_equal(np.asarray(arr), landed[key][..., :width]), key
+        assert not landed[key][..., width:].any(), key
+        shipped[key] = landed[key][..., :width]
+    return shipped
 
 
 # ------------------------------------------------- round-trip property
@@ -122,10 +135,7 @@ def test_export_import_round_trip(data):
         for rec, payload in zip(man.pages, landed):
             assert any("scale" in k for k in payload), \
                 "int8 pool must ship its scale leaves"
-            for key in rec.payload:
-                assert np.array_equal(np.asarray(rec.payload[key]),
-                                      payload[key]), key
-            prev = _page_digest(prev, rec.tokens, payload)
+            prev = _page_digest(prev, rec.tokens, assert_landed(rec, payload))
             assert prev == rec.digest
         for page in entry.pages:
             assert dst.kv.ref.get(page, 0) >= 1
@@ -138,6 +148,40 @@ def test_export_import_round_trip(data):
     assert dst.kv.reserved_pages == 0
     done = {r.req_id: list(r.output_tokens) for r in reqs}
     assert done == base                          # token-identical merge
+
+
+def test_round_trip_ships_head_dim_lanes_of_padded_pool():
+    """head_dim 80 pools pad to 128 lanes on the device: a lane ships
+    only its 80 K/V lanes per head, lands in them with zero pad lanes
+    beside, and the destination finishes token-identical."""
+    cfg = CFG.replace(attn=dataclasses.replace(CFG.attn, head_dim=80))
+    mk = lambda: ServingEngine(cfg, max_slots=4, seq_cap=64, page_size=4,
+                               seed=0, backend="paged", pool_pages=48,
+                               chunk_tokens=8, attn_impl="ref")
+    prompt = np.random.default_rng(5).integers(0, cfg.vocab_size, 11)
+    ref = mk()
+    base = mk_req(0, prompt)
+    assert ref.submit(base)
+    run_out(ref)
+    src = mk()
+    req = mk_req(0, prompt)
+    assert src.submit(req)
+    t = 0.0
+    for _ in range(4):                           # prefill + some decode
+        t += 0.01
+        src.finalize_step(src.step(), t, t - 0.01)
+    (man,) = src.drain_requests(ship_state=True)
+    assert man.warm
+    dst = mk()
+    assert PageImporter(dst.runtime).import_lane(man)
+    entry = dst.kv.tables[0]
+    for rec, landed in zip(man.pages,
+                           dst_pool_payload(dst.runtime, entry.pages)):
+        assert {a.shape[-1] for k, a in rec.payload.items()} == {80}
+        assert {a.shape[-1] for a in landed.values()} == {128}
+        assert_landed(rec, landed)
+    run_out(dst, t)
+    assert list(req.output_tokens) == list(base.output_tokens)
 
 
 # -------------------------------------------- corruption -> recompute

@@ -99,6 +99,71 @@ def test_paged_dense_token_parity(impl):
     assert_no_leaks(dense)
 
 
+@pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
+def test_row_write_changes_only_the_written_rows(kv_dtype):
+    """One fused step over a 3-layer stack writes each layer's new K/V
+    rows (and int8 scales) into that layer's pages of the stacked pools
+    in place: the pool rows that change are exactly (layer, page, head,
+    offset) of the real rows, in every layer, besides the trash page
+    that takes the masked rows; the written rows' pad lanes are zero."""
+    import jax.numpy as jnp
+    cfg = CFG.replace(repeats=3)
+    eng = ServingEngine(cfg, max_slots=4, seq_cap=64, page_size=8, seed=0,
+                        backend="paged", pool_pages=12, chunk_tokens=16,
+                        attn_impl="ref", kv_dtype=kv_dtype)
+    rt = eng.runtime
+    lanes = {"k": cfg.attn.head_dim, "v": cfg.attn.head_dim,
+             "k_scale": cfg.attn.num_kv_heads,
+             "v_scale": cfg.attn.num_kv_heads}
+    # 5 real rows of two lanes (pages 3 then 7, and 2 then 9) and 3
+    # masked rows, which go to the trash page
+    positions = np.asarray([6, 7, 8, 0, 9, 0, 0, 0], np.int32)
+    tables = np.zeros((8, 2), np.int32)
+    tables[:3] = [3, 7]
+    tables[3:5] = [2, 9]
+    real = [(3, 6), (3, 7), (7, 0), (2, 0), (9, 1)]     # (page, offset)
+    rng = np.random.default_rng(0)
+    before = {key: (rng.standard_normal(x.shape) * 50).astype(x.dtype)
+              for key, x in rt.pools["period"]["sub0"].items()}
+    pools = {"period": {"sub0": {k: jnp.asarray(v)
+                                 for k, v in before.items()}}}
+    _, after = rt._mixed_fn(rt.params, pools, np.arange(8, dtype=np.int32),
+                            positions, np.int32(5), tables,
+                            np.arange(4, dtype=np.int32))
+    for key, old in before.items():
+        new = np.asarray(after["period"]["sub0"][key])
+        # rows [L, P+1, KV, page] of K/V, [L, P+1, page] of the scales
+        changed = (new != old).any(-1)
+        want = np.zeros_like(changed)
+        for page, off in real:
+            want[:, page, ..., off] = True
+        trash = rt.pool_pages
+        assert (changed[:, :trash] == want[:, :trash]).all(), key
+        assert changed[:, trash].reshape(3, -1).any(1).all(), key
+        assert not new[..., lanes[key]:][want].any(), key
+
+
+@pytest.mark.parametrize("impl", ["ref", "kernel"])
+def test_stacked_pool_greedy_parity_across_layers(impl):
+    """Three layers in one stacked pool, each read at its own index: the
+    paged path's greedy tokens, through the kernel (interpret mode) and
+    through the oracle, equal the dense backend's over several steps."""
+    cfg = CFG.replace(repeats=3)
+    trace = [(19, 5), (6, 6), (33, 3)]
+    dense = ServingEngine(cfg, max_slots=4, seq_cap=64, page_size=8, seed=0)
+    paged = ServingEngine(cfg, max_slots=4, seq_cap=64, page_size=8, seed=0,
+                          backend="paged", chunk_tokens=16, attn_impl=impl)
+    reqs_d, reqs_p = make_trace(trace=trace), make_trace(trace=trace)
+    for eng, reqs in ((dense, reqs_d), (paged, reqs_p)):
+        for r in reqs:
+            assert eng.submit(r)
+        drain(eng)
+    for rd, rp in zip(reqs_d, reqs_p):
+        assert len(rp.output_tokens) == rp.max_new_tokens
+        assert rd.output_tokens == rp.output_tokens, rd.req_id
+    assert_no_leaks(paged)
+
+
 def test_paged_accounting_during_run():
     """Refcount/occupancy invariants hold at every step (shared pages
     counted once, refcounts consistent, free/cached/owned partition the
@@ -116,8 +181,9 @@ def test_paged_accounting_during_run():
 
 def test_logits_bucket_follows_row_bucket():
     """Without drafts the logit rows pad to one per slot within the row
-    bucket, so however many lanes a step carries, its executable is
-    keyed by (rows, width) alone."""
+    bucket, and every block table is handed over at its full width, so
+    however many lanes a step carries and however long they are, its
+    executable is keyed by the row bucket alone."""
     eng = ServingEngine(CFG, max_slots=4, seq_cap=96, page_size=8, seed=0,
                         backend="paged", chunk_tokens=16, attn_impl="ref")
     reqs = make_trace(seed=4)
@@ -128,6 +194,7 @@ def test_logits_bucket_follows_row_bucket():
     keys = list(eng.runtime.compile_s)
     assert any(t > 4 for t, _, _ in keys) and any(t < 4 for t, _, _ in keys)
     assert all(n == min(t, 4) for t, _, n in keys), keys
+    assert all(w == eng.runtime.pps for _, w, _ in keys), keys
 
 
 # --------------------------------------------------- fused mixed stepping

@@ -19,8 +19,8 @@ from repro.serving.trace import FlightRecorder
 CFG = reduced(get_config("stablelm_3b")).replace(dtype="float32")
 STEP_SPANS = ["step.plan", "step.pack", "step.put", "step.compile",
               "step.device", "step.fetch", "step.commit"]
-SCOPES = ["embed", "attn_in", "kv_scatter", "kv_pool", "attn_kernel",
-          "attn_out", "ffn", "logits", "layer_weights"]
+SCOPES = ["embed", "attn_in", "kv_scatter", "attn_kernel", "attn_out",
+          "ffn", "logits", "layer_weights"]
 
 
 class FakeClock:
